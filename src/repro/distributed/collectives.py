@@ -16,14 +16,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.jax_compat import axis_size, shard_map
-
 
 def ring_all_gather(x, axis_name: str):
     """Inside shard_map: gather shards over `axis_name` with N-1
     collective-permutes (ring schedule).  x: (chunk, ...) local shard.
     Returns (N*chunk, ...) — bitwise equal to jax.lax.all_gather."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     pieces = [x]
@@ -41,7 +39,7 @@ def ring_all_gather(x, axis_name: str):
 
 def reduce_scatter_then_gather(x, axis_name: str):
     """all_reduce(x) == all_gather(reduce_scatter(x)); explicit phases."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     assert x.shape[0] % n == 0
     scattered = jax.lax.psum_scatter(x, axis_name, scatter_dimension=0,
                                      tiled=True)
@@ -52,7 +50,7 @@ def make_ring_all_gather(mesh, axis_name: str):
     """jit-able global-array wrapper around ring_all_gather."""
     def fn(x):
         body = lambda s: ring_all_gather(s, axis_name)
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=P(axis_name), out_specs=P(), check_vma=False)(x)
     return jax.jit(fn)

@@ -24,8 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.jax_compat import axis_size, shard_map
-
 
 def bubble_fraction(n_micro: int, n_stages: int) -> float:
     return (n_stages - 1) / (n_micro + n_stages - 1)
@@ -48,7 +46,7 @@ def gpipe_forward(stage_fn: Callable, stage_params, micro_x, *,
         # params_stage: leading dim 1 (this stage's slice); xs: (M, b, ...)
         p_local = jax.tree.map(lambda a: a[0], params_stage)
         idx = jax.lax.axis_index(axis_name)
-        S = axis_size(axis_name)
+        S = jax.lax.axis_size(axis_name)
         perm = [(i, i + 1) for i in range(n_stages - 1)]
 
         buf = jnp.zeros_like(xs[0])                 # current stage input
@@ -85,7 +83,7 @@ def gpipe_forward(stage_fn: Callable, stage_params, micro_x, *,
         return outs
 
     pspec = jax.tree.map(lambda _: P(axis_name), stage_params)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspec, P()), out_specs=P(), check_vma=False)
     return fn(stage_params, micro_x)

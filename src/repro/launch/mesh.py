@@ -12,7 +12,13 @@ from __future__ import annotations
 
 import jax
 
-from repro.jax_compat import make_mesh
+
+def make_mesh(axis_shapes, axis_names):
+    """``jax.make_mesh`` with every axis Auto (the sharding rules here
+    are written for Auto axes; jax's default is Explicit)."""
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

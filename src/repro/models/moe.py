@@ -28,8 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.jax_compat import shard_map
-
 from repro.models.common import dense_init, pdtype, split_keys
 
 
@@ -187,7 +185,7 @@ def apply_moe(p, x, cfg, ctx: ShardCtx = LOCAL_CTX):
             p_specs[name] = w_spec
     aux_spec = {"load_balance": P(), "router_z": P()}
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(p_specs, x_spec),
         out_specs=(x_spec, aux_spec),

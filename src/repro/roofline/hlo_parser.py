@@ -37,28 +37,13 @@ _COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
 
 
 def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` as a flat dict across jaxlib versions.
-
-    Older jaxlibs return a list with one dict per partition (we sum across
-    them — "flops" etc. are per-executable totals); newer ones return the
-    dict directly; either may be None/empty for trivial programs.
-    """
+    """``compiled.cost_analysis()`` as a dict (empty when the backend
+    reports nothing for the program)."""
     try:
         cost = compiled.cost_analysis()
     except Exception:
         return {}
-    if cost is None:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        merged: Dict[str, float] = {}
-        for part in cost:
-            for k, v in dict(part).items():
-                if isinstance(v, (int, float)):
-                    merged[k] = merged.get(k, 0.0) + float(v)
-                else:
-                    merged.setdefault(k, v)
-        return merged
-    return dict(cost)
+    return dict(cost or {})
 
 
 def _shape_elems_bytes(dt: str, dims: str) -> Tuple[int, int]:

@@ -63,6 +63,11 @@ def pallas_rowwise_int8(x: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
     return np.asarray(q), np.asarray(s)
 
 
+#: the text encoder as one compiled program per (cfg, batch) rather than
+#: an eager dispatch per op
+_encode_prompt = jax.jit(dif.encode_prompt, static_argnums=1)
+
+
 def _new_stats() -> Dict[str, Any]:
     return {"gpu_seconds": 0.0, "compile_seconds": 0.0,
             "bytes_shipped": 0, "requests": 0, "executables": 0,
@@ -162,7 +167,7 @@ class DiffusionSplitEngine:
         cond = jnp.asarray(np.concatenate([r.cond_tokens for r in requests]))
         uncond = jnp.asarray(
             np.concatenate([r.uncond_tokens for r in requests]))
-        ctx2 = dif.encode_prompt(self.params, cfg, cond, uncond)
+        ctx2 = _encode_prompt(self.params, cfg, cond, uncond)
         latent = jax.random.normal(
             jax.random.PRNGKey(seed),
             (B, cfg.latent_channels, cfg.latent_size, cfg.latent_size))

@@ -1,6 +1,5 @@
 """tools/calibrate_r_cloud.py — the roofline-vs-measured calibration
-hook (offline path; the --measure path needs real hardware and is not
-exercised in CI)."""
+hook."""
 import importlib.util
 import json
 import subprocess

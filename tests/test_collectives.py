@@ -11,8 +11,7 @@ from repro.distributed.collectives import (
     reduce_scatter_then_gather,
     ring_all_gather,
 )
-from repro.jax_compat import make_mesh, shard_map
-from repro.launch.mesh import make_host_mesh
+from repro.launch.mesh import make_host_mesh, make_mesh
 
 
 def test_ring_all_gather_matches_all_gather():
@@ -34,7 +33,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np, jax, jax.numpy as jnp
 from repro.distributed.collectives import make_ring_all_gather
-from repro.jax_compat import make_mesh
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((8,), ("x",))
 x = jnp.arange(8 * 2 * 3, dtype=jnp.float32).reshape(16, 3)
 got = make_ring_all_gather(mesh, "x")(x)
@@ -59,10 +58,10 @@ def test_reduce_scatter_then_gather_is_all_reduce():
     def body(s):
         return reduce_scatter_then_gather(s, "x")
 
-    got = jax.jit(shard_map(body, mesh=mesh, in_specs=P("x"),
+    got = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("x"),
                             out_specs=P("x"), check_vma=False))(x)
     def ref(s):
         return jax.lax.psum(s, "x")
-    want = jax.jit(shard_map(ref, mesh=mesh, in_specs=P("x"),
+    want = jax.jit(jax.shard_map(ref, mesh=mesh, in_specs=P("x"),
                              out_specs=P("x"), check_vma=False))(x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want))

@@ -22,7 +22,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import numpy as np, jax, jax.numpy as jnp
 from repro.distributed.pipeline import gpipe_forward
-from repro.jax_compat import make_mesh
+from repro.launch.mesh import make_mesh
 
 S, M, B, D = 4, 6, 2, 8
 mesh = make_mesh((S,), ("stage",))

@@ -8,12 +8,9 @@ but were never validated against real hardware — the open ROADMAP Perf
 item.  This tool closes the loop:
 
   1. read dryrun.jsonl records (one per arch x cell x mesh),
-  2. obtain a MEASURED step time for each record — either by executing
-     one real compiled engine step (``--measure``, the launch/perf.py
-     lowering path; needs the jax toolchain and enough host memory for
-     the model), or from caller-supplied timings (``--step-time`` for a
-     single record, ``--timings-json`` for a batch — e.g. numbers taken
-     from a production profiler),
+  2. take a MEASURED step time for each record from caller-supplied
+     timings (``--step-time`` for a single record, ``--timings-json`` for
+     a batch — numbers taken on the chip, e.g. from a profiler),
   3. emit each record back out with a ``calibration_ratio`` column
      (measured rate / roofline-estimated rate for ``--hw``; 1.0 means
      the roofline was exact, < 1 means hardware is slower than the
@@ -23,14 +20,10 @@ item.  This tool closes the loop:
      ratio, replacing hand calibration.
 
 Examples:
-    # offline: one record, profiler-measured 21.5 ms/step
+    # one record, measured at 21.5 ms/step on the chip
     python tools/calibrate_r_cloud.py --dryrun dryrun.jsonl \
         --arch qwen2-7b --cell decode_32k --step-time 0.0215 \
         --out dryrun.jsonl --capacity-out capacity.json
-
-    # live: lower + execute one real step per matching record
-    PYTHONPATH=src python tools/calibrate_r_cloud.py --dryrun \
-        dryrun.jsonl --arch qwen2-7b --cell decode_32k --measure
 """
 import argparse
 import json
@@ -101,49 +94,6 @@ def calibrated_capacity(records, counts=None, cell=None,
     return CloudCapacity.from_roofline(scaled, counts=counts, cell=cell)
 
 
-def measure_step_time(arch, cell, multi_pod=False, warmup=1, iters=3):
-    """Lower + compile one cell (the launch/perf.py path) and time one
-    real executed step on this host's devices.  Heavy: compiles the
-    model and allocates real buffers — run on the serving hardware, not
-    in CI."""
-    import os
-    import sys as _sys
-    if "jax" not in _sys.modules:
-        # the dryrun meshes expect 512 host devices; must be set before
-        # the FIRST jax init (matches repro.launch.dryrun's entry)
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + " --xla_force_host_platform_device_count=512")
-    import time
-
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from repro.launch.dryrun import lower_cell
-    from repro.launch.mesh import make_production_mesh
-
-    mesh = make_production_mesh(multi_pod=multi_pod)
-    lowered, compiled = lower_cell(arch, cell, mesh)
-    # zero-filled inputs matching the lowered avals (donated args are
-    # re-built per call; timing uses fresh buffers each iteration)
-    def make_args():
-        return jax.tree.map(
-            lambda a: jnp.zeros(a.shape, a.dtype),
-            lowered.in_avals)
-    times = []
-    for i in range(warmup + iters):
-        args = make_args()
-        t0 = time.perf_counter()
-        out = compiled(*args)
-        jax.tree.map(lambda x: x.block_until_ready()
-                     if hasattr(x, "block_until_ready") else x, out)
-        dt = time.perf_counter() - t0
-        if i >= warmup:
-            times.append(dt)
-    return float(np.median(times))
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--dryrun", default="dryrun.jsonl",
@@ -158,9 +108,6 @@ def main():
                          "records (offline calibration)")
     ap.add_argument("--timings-json", default=None,
                     help='JSON file {"arch/cell": seconds, ...}')
-    ap.add_argument("--measure", action="store_true",
-                    help="execute one real engine step per matching "
-                         "record (needs the jax toolchain + memory)")
     ap.add_argument("--out", default=None,
                     help="write calibrated records here (jsonl; default "
                          "overwrite --dryrun in place)")
@@ -191,15 +138,6 @@ def main():
             sec = args.step_time
         elif key in timings:
             sec = timings[key]
-        elif args.measure:
-            try:
-                sec = measure_step_time(rec["arch"], rec["cell"],
-                                        multi_pod="2x" in
-                                        str(rec.get("mesh", "")))
-            except Exception as e:          # missing toolchain / memory
-                print(f"SKIP {key}: measurement failed "
-                      f"({type(e).__name__}: {e})", file=sys.stderr)
-                continue
         else:
             continue
         calibrate_record(rec, sec, hw=args.hw)
@@ -210,8 +148,8 @@ def main():
                   f"{1.0 / rec['r_cloud_est'][args.hw] * 1e3:.2f} ms -> "
                   f"calibration_ratio={rec['calibration_ratio']}")
     if not n:
-        raise SystemExit("nothing calibrated: pass --step-time, "
-                         "--timings-json, or --measure")
+        raise SystemExit("nothing calibrated: pass --step-time or "
+                         "--timings-json")
 
     out = args.out or args.dryrun
     with open(out, "w") as f:
