@@ -106,6 +106,7 @@ def init_text_encoder(cfg, key) -> Params:
     }
 
 
+@jax.named_scope("text_encoder")
 def encode_text(p, cfg, tokens):
     """tokens (B, 77) -> context (B, 77, width).  Causal, CLIP-style."""
     x = p["tok"][tokens] + p["pos"][None, : tokens.shape[1]]
@@ -143,6 +144,7 @@ def init_resblock(key, c_in, c_out, t_dim):
     return p
 
 
+@jax.named_scope("resblock")
 def apply_resblock(p, x, t_emb):
     h = conv2d(silu(gn(p["gn1"], x)), p["conv1"])
     h = h + jnp.einsum("bt,tc->bc", silu(t_emb), p["t_proj"])[:, :, None, None]
@@ -170,26 +172,36 @@ def init_xattn(key, c, ctx_dim, heads):
 
 
 def apply_xattn(p, x, ctx, heads):
-    """Spatial transformer: self-attn + cross-attn(ctx) + MLP."""
+    """Spatial transformer: self-attn + cross-attn(ctx) + MLP.  Each of
+    the three named scopes holds its layer norm, projections and
+    residual add; ``xattn_proj`` holds the way in and out of the
+    sequence layout."""
     B, C, H, W = x.shape
-    h = conv2d(gn(p["gn"], x), p["proj_in"])
-    seq = h.reshape(B, C, H * W).transpose(0, 2, 1)          # (B, HW, C)
-    t = ln(p["ln1"], seq)
-    k, v = jnp.split(jnp.einsum("bsc,ce->bse", t, p["wkv1"]), 2, -1)
-    seq = seq + jnp.einsum(
-        "bsc,ce->bse",
-        _mha(jnp.einsum("bsc,ce->bse", t, p["wq1"]), k, v, heads), p["wo1"])
-    t = ln(p["ln2"], seq)
-    k, v = jnp.split(jnp.einsum("bsc,ce->bse", ctx, p["wkv2"]), 2, -1)
-    seq = seq + jnp.einsum(
-        "bsc,ce->bse",
-        _mha(jnp.einsum("bsc,ce->bse", t, p["wq2"]), k, v, heads), p["wo2"])
-    t = ln(p["ln3"], seq)
-    seq = seq + jnp.einsum(
-        "bsf,fc->bsc", jax.nn.gelu(jnp.einsum("bsc,cf->bsf", t, p["w1"])),
-        p["w2"])
-    h = seq.transpose(0, 2, 1).reshape(B, C, H, W)
-    return x + conv2d(h, p["proj_out"])
+    with jax.named_scope("xattn_proj"):
+        h = conv2d(gn(p["gn"], x), p["proj_in"])
+        seq = h.reshape(B, C, H * W).transpose(0, 2, 1)      # (B, HW, C)
+    with jax.named_scope("self_attn"):
+        t = ln(p["ln1"], seq)
+        k, v = jnp.split(jnp.einsum("bsc,ce->bse", t, p["wkv1"]), 2, -1)
+        seq = seq + jnp.einsum(
+            "bsc,ce->bse",
+            _mha(jnp.einsum("bsc,ce->bse", t, p["wq1"]), k, v, heads),
+            p["wo1"])
+    with jax.named_scope("cross_attn"):
+        t = ln(p["ln2"], seq)
+        k, v = jnp.split(jnp.einsum("bsc,ce->bse", ctx, p["wkv2"]), 2, -1)
+        seq = seq + jnp.einsum(
+            "bsc,ce->bse",
+            _mha(jnp.einsum("bsc,ce->bse", t, p["wq2"]), k, v, heads),
+            p["wo2"])
+    with jax.named_scope("mlp"):
+        t = ln(p["ln3"], seq)
+        seq = seq + jnp.einsum(
+            "bsf,fc->bsc", jax.nn.gelu(jnp.einsum("bsc,cf->bsf", t, p["w1"])),
+            p["w2"])
+    with jax.named_scope("xattn_proj"):
+        h = seq.transpose(0, 2, 1).reshape(B, C, H, W)
+        return x + conv2d(h, p["proj_out"])
 
 
 def init_unet(cfg, key) -> Params:
@@ -247,36 +259,50 @@ def init_unet(cfg, key) -> Params:
     return p
 
 
+@jax.named_scope("unet")
 def apply_unet(p, cfg, latent, t, ctx):
-    """latent (B,4,h,w), t (B,), ctx (B,77,width) -> predicted noise."""
-    t_emb = _timestep_embedding(t, cfg.unet_base)
-    t_emb = jnp.einsum("bt,te->be", silu(jnp.einsum(
-        "bt,te->be", t_emb, p["t_w1"])), p["t_w2"])
-    x = conv2d(latent, p["conv_in"])
+    """latent (B,4,h,w), t (B,), ctx (B,77,width) -> predicted noise.
+
+    Named scopes: one per level (``down{l}``, ``mid``, ``up{l}``, with
+    ``l`` the resolution level, 0 the finest), and inside them the
+    blocks (``resblock``, ``xattn_proj``, ``self_attn``, ``cross_attn``,
+    ``mlp``) and ``resample``; ``stem`` and ``head`` outside."""
+    with jax.named_scope("stem"):
+        t_emb = _timestep_embedding(t, cfg.unet_base)
+        t_emb = jnp.einsum("bt,te->be", silu(jnp.einsum(
+            "bt,te->be", t_emb, p["t_w1"])), p["t_w2"])
+        x = conv2d(latent, p["conv_in"])
     skips = [x]
-    for lvl_p in p["downs"]:
-        for blk in lvl_p["blocks"]:
-            x = apply_resblock(blk["res"], x, t_emb)
-            if "attn" in blk:
-                x = apply_xattn(blk["attn"], x, ctx, cfg.unet_heads)
-            skips.append(x)
-        if "down" in lvl_p:
-            x = conv2d(x, lvl_p["down"], stride=2)
-            skips.append(x)
-    x = apply_resblock(p["mid1"], x, t_emb)
-    x = apply_xattn(p["mid_attn"], x, ctx, cfg.unet_heads)
-    x = apply_resblock(p["mid2"], x, t_emb)
-    for lvl_p in p["ups"]:
-        for blk in lvl_p["blocks"]:
-            x = jnp.concatenate([x, skips.pop()], axis=1)
-            x = apply_resblock(blk["res"], x, t_emb)
-            if "attn" in blk:
-                x = apply_xattn(blk["attn"], x, ctx, cfg.unet_heads)
-        if "up" in lvl_p:
-            B, C, H, W = x.shape
-            x = jax.image.resize(x, (B, C, 2 * H, 2 * W), "nearest")
-            x = conv2d(x, lvl_p["up"])
-    return conv2d(silu(gn(p["gn_out"], x)), p["conv_out"])
+    for lvl, lvl_p in enumerate(p["downs"]):
+        with jax.named_scope(f"down{lvl}"):
+            for blk in lvl_p["blocks"]:
+                x = apply_resblock(blk["res"], x, t_emb)
+                if "attn" in blk:
+                    x = apply_xattn(blk["attn"], x, ctx, cfg.unet_heads)
+                skips.append(x)
+            if "down" in lvl_p:
+                with jax.named_scope("resample"):
+                    x = conv2d(x, lvl_p["down"], stride=2)
+                skips.append(x)
+    with jax.named_scope("mid"):
+        x = apply_resblock(p["mid1"], x, t_emb)
+        x = apply_xattn(p["mid_attn"], x, ctx, cfg.unet_heads)
+        x = apply_resblock(p["mid2"], x, t_emb)
+    for i, lvl_p in enumerate(p["ups"]):
+        with jax.named_scope(f"up{len(p['ups']) - 1 - i}"):
+            for blk in lvl_p["blocks"]:
+                with jax.named_scope("resblock"):    # the skip joins its input
+                    x = jnp.concatenate([x, skips.pop()], axis=1)
+                x = apply_resblock(blk["res"], x, t_emb)
+                if "attn" in blk:
+                    x = apply_xattn(blk["attn"], x, ctx, cfg.unet_heads)
+            if "up" in lvl_p:
+                with jax.named_scope("resample"):
+                    B, C, H, W = x.shape
+                    x = jax.image.resize(x, (B, C, 2 * H, 2 * W), "nearest")
+                    x = conv2d(x, lvl_p["up"])
+    with jax.named_scope("head"):
+        return conv2d(silu(gn(p["gn_out"], x)), p["conv_out"])
 
 
 # ==========================================================================
@@ -345,19 +371,23 @@ def encode_prompt(params, cfg, cond_tokens, uncond_tokens):
 
 
 def denoise_step(params, cfg, latent, ctx2, step_idx):
-    """One guided DDIM step.  ctx2 (2,B,77,w); step_idx scalar int32."""
-    alphas, t_idx = ddim_alphas(cfg)
-    a_t = alphas[step_idx]
-    a_prev = jnp.where(step_idx + 1 < cfg.n_total_iterations,
-                       alphas[jnp.minimum(step_idx + 1,
-                                          cfg.n_total_iterations - 1)],
-                       jnp.float32(1.0))
-    t = jnp.broadcast_to(t_idx[step_idx], (latent.shape[0],))
+    """One guided DDIM step.  ctx2 (2,B,77,w); step_idx scalar int32.
+    The schedule, the guidance combine and the DDIM update are the
+    named scope ``guidance``; the two UNet runs are ``unet``."""
+    with jax.named_scope("guidance"):
+        alphas, t_idx = ddim_alphas(cfg)
+        a_t = alphas[step_idx]
+        a_prev = jnp.where(step_idx + 1 < cfg.n_total_iterations,
+                           alphas[jnp.minimum(step_idx + 1,
+                                              cfg.n_total_iterations - 1)],
+                           jnp.float32(1.0))
+        t = jnp.broadcast_to(t_idx[step_idx], (latent.shape[0],))
     eps_u = apply_unet(params["unet"], cfg, latent, t, ctx2[0])
     eps_c = apply_unet(params["unet"], cfg, latent, t, ctx2[1])
-    eps = eps_u + cfg.guidance_scale * (eps_c - eps_u)
-    x0 = (latent - jnp.sqrt(1.0 - a_t) * eps) / jnp.sqrt(a_t)
-    return jnp.sqrt(a_prev) * x0 + jnp.sqrt(1.0 - a_prev) * eps
+    with jax.named_scope("guidance"):
+        eps = eps_u + cfg.guidance_scale * (eps_c - eps_u)
+        x0 = (latent - jnp.sqrt(1.0 - a_t) * eps) / jnp.sqrt(a_t)
+        return jnp.sqrt(a_prev) * x0 + jnp.sqrt(1.0 - a_prev) * eps
 
 
 def denoise_range(params, cfg, latent, ctx2, start_iter: int, stop_iter: int):

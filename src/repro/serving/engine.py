@@ -13,6 +13,13 @@ finishes [g, G) + the LM head.
 
 Both engines measure their own executable-cache size, GPU-seconds and
 bytes shipped, which the benchmarks aggregate.
+
+``DiffusionSplitEngine.process_group`` marks its host stages with
+profiler spans (``repro.engine.*``: process_group, encode_prompt,
+compile, denoise, pull, pack), and every jitted program here has a
+stable name (``jit_encode_prompt``, ``jit_denoise_range``,
+``jit_device_finish``, ``jit_cloud_layers``, ``jit_device_layers``).
+A span costs about a microsecond while no profiler runs.
 """
 from __future__ import annotations
 
@@ -23,6 +30,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.cost_model import CostParams
 from repro.core.planner import PlanRequest, Planner
@@ -132,12 +140,15 @@ class DiffusionSplitEngine:
         self.stats["cache_misses"] += 1
         cfg = self.cfg
 
-        def fn(params, latent, ctx2):
+        def denoise_range(params, latent, ctx2):
             return dif.denoise_range(params, cfg, latent, ctx2, 0,
                                      n_cloud)
-        t0 = time.perf_counter()
-        compiled = jax.jit(fn).lower(self.params, latent, ctx2).compile()
-        self.stats["compile_seconds"] += time.perf_counter() - t0
+        with TraceAnnotation("repro.engine.compile", n_cloud=n_cloud,
+                             batch=batch):
+            t0 = time.perf_counter()
+            compiled = jax.jit(denoise_range).lower(
+                self.params, latent, ctx2).compile()
+            self.stats["compile_seconds"] += time.perf_counter() - t0
         self._exec_cache[key] = compiled
         self.stats["executables"] = len(self._exec_cache)
         return compiled
@@ -164,40 +175,52 @@ class DiffusionSplitEngine:
             return []
         cfg = self.cfg
         B = len(requests)
-        cond = jnp.asarray(np.concatenate([r.cond_tokens for r in requests]))
-        uncond = jnp.asarray(
-            np.concatenate([r.uncond_tokens for r in requests]))
-        ctx2 = _encode_prompt(self.params, cfg, cond, uncond)
-        latent = jax.random.normal(
-            jax.random.PRNGKey(seed),
-            (B, cfg.latent_channels, cfg.latent_size, cfg.latent_size))
-        gpu_s = 0.0
-        if n_cloud > 0:
-            run = self._denoise_fn(n_cloud, B, latent, ctx2)  # warm first
-            t0 = time.perf_counter()
-            latent = run(self.params, latent, ctx2)
-            latent.block_until_ready()
-            gpu_s = time.perf_counter() - t0
-        results = []
-        lat_np = np.asarray(latent, np.float32)
-        ctx_np = np.asarray(ctx2, np.float32)
-        for i, r in enumerate(requests):
-            need_ctx = n_cloud < cfg.n_total_iterations
-            ctx_i = ctx_np[:, i] if need_ctx else None
-            if self.wire is not None:
-                payload = pack_boundary_wire(lat_np[i], ctx_i, self.wire,
-                                             rowwise=pallas_rowwise_int8)
-            else:
-                payload = pack_boundary(lat_np[i], ctx_i,
-                                        mode=self.transfer_mode)
-            t_net = transmission_time(len(payload), self.link)
-            results.append(SplitResult(
-                request_id=r.request_id, n_cloud=n_cloud, payload=payload,
-                cloud_seconds=gpu_s / B, transfer_seconds=t_net))
-            self.stats["bytes_shipped"] += len(payload)
-        self.stats["gpu_seconds"] += gpu_s
-        self.stats["requests"] += B
-        return results
+        with TraceAnnotation(
+                "repro.engine.process_group", n_cloud=n_cloud, batch=B,
+                request_ids=";".join(r.request_id for r in requests)):
+            with TraceAnnotation("repro.engine.encode_prompt"):
+                cond = jnp.asarray(
+                    np.concatenate([r.cond_tokens for r in requests]))
+                uncond = jnp.asarray(
+                    np.concatenate([r.uncond_tokens for r in requests]))
+                ctx2 = _encode_prompt(self.params, cfg, cond, uncond)
+                latent = jax.random.normal(
+                    jax.random.PRNGKey(seed),
+                    (B, cfg.latent_channels, cfg.latent_size,
+                     cfg.latent_size))
+            gpu_s = 0.0
+            if n_cloud > 0:
+                run = self._denoise_fn(n_cloud, B, latent, ctx2)  # warm first
+                with TraceAnnotation("repro.engine.denoise"):
+                    t0 = time.perf_counter()
+                    latent = run(self.params, latent, ctx2)
+                    latent.block_until_ready()
+                    gpu_s = time.perf_counter() - t0
+            results = []
+            with TraceAnnotation("repro.engine.pull"):
+                lat_np = np.asarray(latent, np.float32)
+                ctx_np = np.asarray(ctx2, np.float32)
+            for i, r in enumerate(requests):
+                with TraceAnnotation("repro.engine.pack",
+                                     request_id=r.request_id):
+                    need_ctx = n_cloud < cfg.n_total_iterations
+                    ctx_i = ctx_np[:, i] if need_ctx else None
+                    if self.wire is not None:
+                        payload = pack_boundary_wire(
+                            lat_np[i], ctx_i, self.wire,
+                            rowwise=pallas_rowwise_int8)
+                    else:
+                        payload = pack_boundary(lat_np[i], ctx_i,
+                                                mode=self.transfer_mode)
+                    t_net = transmission_time(len(payload), self.link)
+                results.append(SplitResult(
+                    request_id=r.request_id, n_cloud=n_cloud,
+                    payload=payload, cloud_seconds=gpu_s / B,
+                    transfer_seconds=t_net))
+                self.stats["bytes_shipped"] += len(payload)
+            self.stats["gpu_seconds"] += gpu_s
+            self.stats["requests"] += B
+            return results
 
     def serve(self, requests: List[Request], seed: int = 0
               ) -> Dict[str, SplitResult]:
@@ -237,12 +260,13 @@ class DiffusionDeviceSim:
         if run is None:
             self.stats["cache_misses"] += 1
 
-            def fn(params, latent, ctx2):
+            def device_finish(params, latent, ctx2):
                 out = dif.denoise_range(params, cfg, latent, ctx2, n0,
                                         cfg.n_total_iterations)
                 return dif.apply_vae_decoder(params["vae"], cfg, out)
             t0 = time.perf_counter()
-            run = jax.jit(fn).lower(self.params, latent, ctx2).compile()
+            run = jax.jit(device_finish).lower(self.params, latent,
+                                               ctx2).compile()
             self.stats["compile_seconds"] += time.perf_counter() - t0
             self._finish_cache[key] = run
             self.stats["executables"] = len(self._finish_cache)
@@ -281,14 +305,14 @@ class LayerSplitEngine:
         self.stats["cache_misses"] += 1
         cfg = self.cfg
 
-        def fn(params, batch):
+        def cloud_layers(params, batch):
             x = tr.embed_inputs(params, batch, cfg)
             positions = jnp.arange(x.shape[1])
             return tr.run_layer_range(
                 params, x, cfg, LOCAL_CTX, start_group=0,
                 stop_group=stop_group, positions=positions)
         t0 = time.perf_counter()
-        compiled = jax.jit(fn).lower(self.params, batch).compile()
+        compiled = jax.jit(cloud_layers).lower(self.params, batch).compile()
         self.stats["compile_seconds"] += time.perf_counter() - t0
         self._exec_cache[key] = compiled
         self.stats["executables"] = len(self._exec_cache)
@@ -326,7 +350,7 @@ class LayerSplitDevice:
         if run is None:
             self.stats["cache_misses"] += 1
 
-            def fn(params, hidden):
+            def device_layers(params, hidden):
                 positions = jnp.arange(hidden.shape[1])
                 x = tr.run_layer_range(
                     params, hidden, cfg, LOCAL_CTX, start_group=start_group,
@@ -334,7 +358,7 @@ class LayerSplitDevice:
                 x = tr.apply_norm(params["final_norm"], x)
                 return tr.unembed(params, x[:, -1:], cfg)
             t0 = time.perf_counter()
-            run = jax.jit(fn).lower(self.params, hidden).compile()
+            run = jax.jit(device_layers).lower(self.params, hidden).compile()
             self.stats["compile_seconds"] += time.perf_counter() - t0
             self._exec_cache[key] = run
             self.stats["executables"] = len(self._exec_cache)

@@ -113,3 +113,51 @@ def test_param_count_analytic_close_to_actual():
             pad *= 2
         est = cfg.param_count()
         assert abs(actual - pad - est) / actual < 0.25, arch
+
+
+def test_diffusion_named_scopes():
+    """The compiled denoise loop and text encoder at the small size carry
+    each named scope in their ``op_name`` metadata, at every level of
+    the UNet: what the benchmark's trace reader puts device time down
+    to."""
+    import re
+
+    from repro.configs import stable_diffusion_v1
+    from repro.models import diffusion
+    cfg = stable_diffusion_v1.reduced()
+    params = jax.eval_shape(lambda k: diffusion.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    lat = jax.ShapeDtypeStruct(
+        (1, cfg.latent_channels, cfg.latent_size, cfg.latent_size),
+        jnp.float32)
+    ctx2 = jax.ShapeDtypeStruct((2, 1, cfg.text_len, cfg.text_width),
+                                jnp.float32)
+    toks = jax.ShapeDtypeStruct((1, cfg.text_len), jnp.int32)
+
+    def op_names(fn, *args):
+        hlo = jax.jit(fn).lower(*args).compile().as_text()
+        return set(re.findall(r'op_name="([^"]*)"', hlo))
+
+    unet = op_names(lambda p, x, c: diffusion.denoise_range(
+        p, cfg, x, c, 0, 2), params, lat, ctx2)
+    text = op_names(lambda p, t: diffusion.encode_text(p["text"], cfg, t),
+                    params, toks)
+
+    def has(names, path):
+        return any(f"/{path}/" in n for n in names)
+    L = len(cfg.unet_mults)
+    levels = ([(f"down{i}", i) for i in range(L)] + [("mid", L - 1)]
+              + [(f"up{i}", i) for i in reversed(range(L))])
+    for level, i in levels:
+        want = ["resblock"]
+        if level == "mid" or i in cfg.unet_attn_levels:
+            want += ["xattn_proj", "self_attn", "cross_attn", "mlp"]
+        if (level.startswith("down") and i < L - 1) or (
+                level.startswith("up") and i > 0):
+            want.append("resample")
+        for scope in want:
+            assert has(unet, f"unet/{level}/{scope}"), (level, scope)
+    for scope in ("unet/stem", "unet/head", "guidance"):
+        assert has(unet, scope), scope
+    assert has(text, "text_encoder")
+    assert not has(unet, "text_encoder")

@@ -91,3 +91,79 @@ def test_layer_split_matches_full_forward():
                                    np.asarray(want, np.float32),
                                    atol=0.15, rtol=0.1)  # fp16 boundary
         assert t_net > 0
+
+
+def _engine_spans(trace_dir):
+    """The ``repro.engine.*`` host events of the one profile under
+    ``trace_dir``: (start ns, end ns, name, {arg: value}), in order."""
+    from jax.profiler import ProfileData
+    path = next(trace_dir.rglob("*.xplane.pb"))
+    out = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("repro.engine."):
+                    out.append((ev.start_ns, ev.end_ns, ev.name,
+                                dict(ev.stats)))
+    return sorted(out, key=lambda e: (e[0], -e[1]))
+
+
+def test_process_group_spans(dmodel, tmp_path):
+    """A traced group shows the engine's host stages in order, each pack
+    with its request; a compile span appears on a cache miss only."""
+    cfg, params = dmodel
+    cost = CostParams(r_cloud=10.0, n_total=cfg.n_total_iterations,
+                      n_step=cfg.split_stride, t_lim=5.0, k_decode=1.0)
+    engine = DiffusionSplitEngine(params, cfg, cost, link=LOCAL_LINK)
+    toks = np.zeros((1, cfg.text_len), np.int32)
+    reqs = [Request(f"r{i}", DeviceProfile(f"d{i}", 5.0), toks, toks)
+            for i in range(2)]
+    with jax.profiler.trace(str(tmp_path)):
+        engine.process_group(reqs, 2, seed=0)        # compiles
+        engine.process_group(reqs, 2, seed=1)        # cached
+    spans = _engine_spans(tmp_path)
+    groups = [s for s in spans if s[2] == "repro.engine.process_group"]
+    assert len(groups) == 2
+    for (g0, g1, _, args), compiles in zip(groups, (1, 0)):
+        assert args == {"n_cloud": 2, "batch": 2, "request_ids": "r0;r1"}
+        inner = [s for s in spans if g0 <= s[0] and s[1] <= g1
+                 and s[2] != "repro.engine.process_group"]
+        assert [s[2].rsplit(".", 1)[1] for s in inner] == (
+            ["encode_prompt"] + ["compile"] * compiles
+            + ["denoise", "pull", "pack", "pack"])
+        assert [s[3] for s in inner if s[2].endswith(".pack")] == [
+            {"request_id": "r0"}, {"request_id": "r1"}]
+        for s in inner:
+            if s[2].endswith(".compile"):
+                assert s[3] == {"n_cloud": 2, "batch": 2}
+    assert engine.stats["cache_misses"] == 1
+    assert engine.stats["cache_hits"] == 1
+
+
+def test_engine_programs_have_stable_names(dmodel):
+    """Each engine's jitted program is named for what it runs, so the
+    trace tells them apart."""
+    cfg, params = dmodel
+    cost = CostParams(r_cloud=10.0, n_total=cfg.n_total_iterations,
+                      n_step=cfg.split_stride, t_lim=5.0, k_decode=1.0)
+    engine = DiffusionSplitEngine(params, cfg, cost, link=LOCAL_LINK)
+    device = DiffusionDeviceSim(params, cfg)
+    toks = np.zeros((1, cfg.text_len), np.int32)
+    res = engine.process_group(
+        [Request("r", DeviceProfile("d", 5.0), toks, toks)],
+        cfg.n_total_iterations - 1)[0]
+    device.complete(res)
+    lcfg = reduced_config("qwen2-7b")
+    lparams = tr.init_params(lcfg, jax.random.PRNGKey(0))
+    cloud = LayerSplitEngine(lparams, lcfg, link=LOCAL_LINK)
+    phone = LayerSplitDevice(lparams, lcfg)
+    payload, _ = cloud.process({"tokens": np.zeros((1, 8), np.int32)}, 1)
+    phone.complete(payload, 1)
+
+    def names(cache):
+        return {c.as_text().split(",", 1)[0].split()[1]
+                for c in cache.values()}
+    assert names(engine._exec_cache) == {"jit_denoise_range"}
+    assert names(device._finish_cache) == {"jit_device_finish"}
+    assert names(cloud._exec_cache) == {"jit_cloud_layers"}
+    assert names(phone._exec_cache) == {"jit_device_layers"}
