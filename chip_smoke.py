@@ -24,7 +24,9 @@ Checks (any failure exits non-zero):
   * the int8 kernel agrees with ``transport.rowwise_quantize_int8``
     within 1 LSB on the boundary tensors;
   * the compiled ``ops.int8_quantize`` holds a ``tpu_custom_call``, i.e.
-    the kernel is compiled for the chip and not interpreted.
+    the kernel is compiled for the chip and not interpreted;
+  * the compiled denoise program holds the flash-attention kernel as a
+    ``tpu_custom_call`` when ``diffusion.flash_sites`` counts any site.
 
 Timings printed here are smoke timings of one run, not benchmark
 numbers.  Without a TPU the script exits non-zero before any work.  The
@@ -136,7 +138,8 @@ def main():
     print(f"config {cfg.name}: latent {cfg.latent_channels}x"
           f"{cfg.latent_size}x{cfg.latent_size}, unet_base {cfg.unet_base}, "
           f"context {cfg.text_len}x{cfg.text_width}, "
-          f"{cfg.n_total_iterations} steps")
+          f"{cfg.n_total_iterations} steps, {dif.flash_sites(cfg)} "
+          f"self-attention layers per UNet run through the flash kernel")
     print("timings below are one smoke run, not benchmark numbers")
 
     t0 = time.perf_counter()
@@ -186,6 +189,11 @@ def main():
     check(finite(img_int8), "mid-split int8 image finite")
     print(f"int8 vs fp32 wire: max |image diff| = "
           f"{float(jnp.max(jnp.abs(img_int8 - img_split))):.6g}")
+
+    denoise_hlo = fp32._exec_cache[(MID_SPLIT, len(mid))].as_text()
+    check(("tpu_custom_call" in denoise_hlo) == (dif.flash_sites(cfg) > 0),
+          f"compiled denoise program holds the flash kernel "
+          f"({dif.flash_sites(cfg)} sites per UNet run)")
 
     lat, ctx = unpack_boundary(mid_fp32.payload)
     rows = check_int8_kernel(lat, "latent")

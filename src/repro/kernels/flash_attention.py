@@ -4,21 +4,29 @@ Online-softmax attention with explicit VMEM tiling:
   grid = (batch * q_heads, q_blocks, kv_blocks)   (kv innermost)
   q block   (1, bq, d)   VMEM
   k/v block (1, bk, d)   VMEM, indexed to the matching GQA kv head
-  scratch   acc (bq, d) f32, m (bq,) f32, l (bq,) f32 — persist across the
-            kv grid dimension (canonical TPU flash pattern).
+  scratch   acc (bq, d) f32, m (bq, 128) f32, l (bq, 128) f32 — persist
+            across the kv grid dimension (canonical TPU flash pattern).
 
 Causal and sliding-window masks are applied per tile; tiles entirely
 outside the mask are skipped with ``pl.when`` (no MXU work issued).
 GQA is handled in the k/v index_map (kv_head = q_head // group), so no
 materialized head repetition.
 
+Precision: both dots take the input dtype as their operands and
+accumulate in fp32 (bf16 inputs give single-pass bf16 MXU dots, fp32
+inputs fp32 dots); the running max, the row sums and the accumulator are
+fp32, and the probabilities are cast to the operand dtype only for the
+PV dot.  Tiles need no mask when nothing is causal, windowed or padded.
+
 Hardware alignment: bq/bk default 512/512; d must be padded to a multiple
-of 128 by the ops.py wrapper (MXU lane width).
+of 128 by the ops.py wrapper (MXU lane width).  On a v5e, non-causal
+attention over 4096 tokens at head_dim 40 (padded) took 0.60 ms per
+8-head image at (512, 1024) with (bq, 128) statistics, 0.92 ms with
+(bq,) vectors.
 """
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +34,17 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+#: lane width of a TPU vector register: the running max and row sums are
+#: kept as (bq, LANES) columns with every lane equal, a layout the
+#: row reductions and broadcasts take without relayout
+LANES = 128
+
+
+def _widen(col, n: int):
+    """A lane-replicated (rows, LANES) column at width ``n``."""
+    if n % LANES == 0:
+        return jnp.tile(col, (1, n // LANES))
+    return col[:, :1]
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
@@ -53,34 +72,36 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)                     # (bq, d)
-        k = k_ref[0].astype(jnp.float32)                     # (bk, d)
-        v = v_ref[0].astype(jnp.float32)
+        q = q_ref[0]                                         # (bq, d)
+        k = k_ref[0]                                         # (bk, d)
+        v = v_ref[0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        mask = k_pos < kv_len
-        if causal:
-            mask = jnp.logical_and(mask, k_pos <= q_pos)
-        if window:
-            mask = jnp.logical_and(mask, k_pos > q_pos - window)
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        if causal or window or kv_len < n_kv * bk:
+            q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+            k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+            mask = k_pos < kv_len
+            if causal:
+                mask = jnp.logical_and(mask, k_pos <= q_pos)
+            if window:
+                mask = jnp.logical_and(mask, k_pos > q_pos - window)
+            s = jnp.where(mask, s, NEG_INF)
+        m_prev = m_ref[...]                                  # (bq, LANES)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - _widen(m_new, bk))
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1)
-        acc_ref[...] = (acc_ref[...] * corr[:, None]
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = (acc_ref[...] * _widen(corr, acc_ref.shape[1])
                         + jax.lax.dot_general(
-                            p, v, (((1,), (0,)), ((), ())),
+                            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32))
         m_ref[...] = m_new
 
     @pl.when(ki == n_kv - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / _widen(l, acc_ref.shape[1])
+                    ).astype(o_ref.dtype)
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
@@ -122,8 +143,8 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         out_shape=jax.ShapeDtypeStruct((BHq, Sq, d), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, LANES), jnp.float32),
+            pltpu.VMEM((bq, LANES), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v)

@@ -23,6 +23,7 @@ from typing import Any, Dict, List, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops
 from repro.models.common import dense_init, embed_init, split_keys
 from repro.models.regnet import conv2d, init_conv
 
@@ -79,6 +80,35 @@ def _mha(q, k, v, heads, causal=False):
     p = jax.nn.softmax(s.astype(jnp.float32), -1).astype(q.dtype)
     o = jnp.einsum("bhqk,bkhd->bqhd", p, v)
     return o.reshape(B, Sq, D)
+
+
+#: Self-attention over at least this many tokens runs through the Pallas
+#: flash kernel, so its (S, S) scores stay in VMEM; shorter sequences,
+#: cross-attention and the text encoder keep ``_mha``.  4096 is the 64x64
+#: level of stable-diffusion-v1; at 1024 tokens (head_dim 80) the einsum
+#: path was the faster on a v5e.
+FLASH_MIN_TOKENS = 4096
+#: The kernel's (q, kv) block pair: the fastest of those measured on a v5e
+#: at 4096 tokens, batch 1 and 2.
+FLASH_BLOCKS = (512, 1024)
+
+
+def _self_attention(q, k, v, heads):
+    """Self-attention of a spatial transformer, q/k/v (B, S, C).
+
+    At ``FLASH_MIN_TOKENS`` tokens or more: the flash kernel, with q, k
+    and v rounded to bf16 once (the operands a default-precision TPU dot
+    takes from fp32 anyway), fp32 softmax statistics and accumulation,
+    and the result in the input dtype."""
+    B, S, D = q.shape
+    if S < FLASH_MIN_TOKENS:
+        return _mha(q, k, v, heads)
+    with jax.named_scope("flash_attention"):
+        o = ops.flash_attention(
+            *(a.astype(jnp.bfloat16).reshape(B, S, heads, D // heads)
+              for a in (q, k, v)), causal=False, bq=FLASH_BLOCKS[0],
+            bk=FLASH_BLOCKS[1])
+        return o.reshape(B, S, D).astype(q.dtype)
 
 
 # ==========================================================================
@@ -185,7 +215,8 @@ def apply_xattn(p, x, ctx, heads):
         k, v = jnp.split(jnp.einsum("bsc,ce->bse", t, p["wkv1"]), 2, -1)
         seq = seq + jnp.einsum(
             "bsc,ce->bse",
-            _mha(jnp.einsum("bsc,ce->bse", t, p["wq1"]), k, v, heads),
+            _self_attention(jnp.einsum("bsc,ce->bse", t, p["wq1"]), k, v,
+                            heads),
             p["wo1"])
     with jax.named_scope("cross_attn"):
         t = ln(p["ln2"], seq)
@@ -303,6 +334,22 @@ def apply_unet(p, cfg, latent, t, ctx):
                     x = conv2d(x, lvl_p["up"])
     with jax.named_scope("head"):
         return conv2d(silu(gn(p["gn_out"], x)), p["conv_out"])
+
+
+def flash_sites(cfg) -> int:
+    """How many self-attention layers of one UNet evaluation run through
+    the flash kernel (``_self_attention``), from the configuration's
+    shapes: level ``l`` has ``unet_res_blocks`` attention blocks down and
+    one more up, the middle one more at the coarsest level."""
+    L = len(cfg.unet_mults)
+    size = cfg.latent_size
+    sites = 0
+    for lvl in range(L):
+        if lvl in cfg.unet_attn_levels and size * size >= FLASH_MIN_TOKENS:
+            sites += 2 * cfg.unet_res_blocks + 1
+        if lvl < L - 1:
+            size = -(-size // 2)                 # stride-2 "SAME" conv
+    return sites + int(size * size >= FLASH_MIN_TOKENS)   # mid
 
 
 # ==========================================================================

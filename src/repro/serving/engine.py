@@ -125,6 +125,9 @@ class DiffusionSplitEngine:
             cost, policy="variable", solve_c_batch=cost.c_batch)
         self._exec_cache: Dict[Tuple[int, int], Any] = {}
         self.stats = _new_stats()
+        #: self-attention layers per UNet run that take the flash kernel,
+        #: recorded on the process_group and compile spans
+        self.flash_sites = dif.flash_sites(cfg)
 
     # -- executable cache: one COMPILED program per (n_final, batch) -------
     def _denoise_fn(self, n_cloud: int, batch: int, latent, ctx2):
@@ -144,7 +147,7 @@ class DiffusionSplitEngine:
             return dif.denoise_range(params, cfg, latent, ctx2, 0,
                                      n_cloud)
         with TraceAnnotation("repro.engine.compile", n_cloud=n_cloud,
-                             batch=batch):
+                             batch=batch, flash_sites=self.flash_sites):
             t0 = time.perf_counter()
             compiled = jax.jit(denoise_range).lower(
                 self.params, latent, ctx2).compile()
@@ -177,6 +180,7 @@ class DiffusionSplitEngine:
         B = len(requests)
         with TraceAnnotation(
                 "repro.engine.process_group", n_cloud=n_cloud, batch=B,
+                flash_sites=self.flash_sites,
                 request_ids=";".join(r.request_id for r in requests)):
             with TraceAnnotation("repro.engine.encode_prompt"):
                 cond = jnp.asarray(

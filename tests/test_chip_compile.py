@@ -73,7 +73,19 @@ def test_int8_quantize_compiles_to_tpu_kernel(one_chip, shape, monkeypatch):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_level0_spatial_transformer_compiles(one_chip):
+@pytest.fixture
+def chip_kernels(monkeypatch):
+    """Steer ``kernels.ops`` to its chip branch (it asks
+    jax.default_backend(), the CPU here), with no jit traced earlier for
+    the CPU reused, nor one traced here reused after."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+def test_level0_spatial_transformer_compiles(one_chip, chip_kernels):
+    # 4096 tokens: the self-attention takes the flash kernel
     p = _sds(jax.eval_shape(
         lambda k: dif.init_xattn(k, LEVEL0, CONFIG.text_width,
                                  CONFIG.unet_heads),
@@ -87,6 +99,7 @@ def test_level0_spatial_transformer_compiles(one_chip):
         lambda p, x, c: dif.apply_xattn(p, x, c, CONFIG.unet_heads),
         p, x, ctx)
     assert compiled.memory_analysis().output_size_in_bytes >= 4 * x.size
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_level0_resblock_compiles(one_chip):

@@ -20,6 +20,7 @@ def _n(*shape, dtype=np.float32):
     (2, 256, 256, 4, 1, 80, True, 64),      # MQA + window + padded head_dim
     (1, 128, 128, 2, 2, 128, False, 0),     # non-causal (cross-attn)
     (1, 512, 512, 3, 3, 64, True, 128),     # odd heads
+    (1, 256, 256, 2, 2, 40, False, 0),      # diffusion self-attn, padded d
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_attention(B, Sq, Skv, Hq, Hkv, D, causal, win, dtype):

@@ -161,3 +161,77 @@ def test_diffusion_named_scopes():
         assert has(unet, scope), scope
     assert has(text, "text_encoder")
     assert not has(unet, "text_encoder")
+
+
+def test_diffusion_flash_self_attention_matches_einsum():
+    """At the smallest shape the rule sends to the flash kernel (one
+    image, 2 heads of head_dim 40, interpret mode here) the kernel path
+    agrees with the einsum ``_mha`` on the same fp32 inputs to within the
+    bf16 rounding of its operands; below the threshold it is ``_mha``."""
+    from repro.models import diffusion
+    S = diffusion.FLASH_MIN_TOKENS
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    q, k, v = (jax.random.normal(kk, (1, S, 80), jnp.float32) for kk in ks)
+    got = diffusion._self_attention(q, k, v, 2)
+    want = diffusion._mha(q, k, v, 2)
+    assert got.dtype == jnp.float32 and got.shape == want.shape
+    err = float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+    # q, k, v, the probabilities and the output are each rounded once to
+    # bf16 (relative error up to 2^-9); the einsum path on the CPU rounds
+    # none of them.  err > 0: the kernel path ran.
+    assert 0 < err < 4 * 2.0 ** -9, err
+    short = [a[:, : S // 2] for a in (q, k, v)]
+    np.testing.assert_array_equal(diffusion._self_attention(*short, 2),
+                                  diffusion._mha(*short, 2))
+
+
+def test_diffusion_flash_sites(monkeypatch):
+    """``flash_sites`` counts the self-attention layers of one UNet run
+    that take the kernel: those of the 64x64 level of stable-diffusion-v1
+    (2 down, 3 up) and none at the small size; with the threshold lowered
+    to the small size's finest level it counts the calls a traced UNet
+    makes."""
+    from repro.configs import stable_diffusion_v1
+    from repro.models import diffusion
+    assert diffusion.flash_sites(stable_diffusion_v1.CONFIG) == 5
+    cfg = stable_diffusion_v1.reduced()
+    assert diffusion.flash_sites(cfg) == 0
+
+    calls = []
+    real = diffusion.ops.flash_attention
+
+    def counted(*args, **kw):
+        calls.append(args[0].shape)
+        return real(*args, **kw)
+    monkeypatch.setattr(diffusion, "FLASH_MIN_TOKENS", cfg.latent_size ** 2)
+    monkeypatch.setattr(diffusion.ops, "flash_attention", counted)
+    params = jax.eval_shape(lambda k: diffusion.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    lat = jax.ShapeDtypeStruct(
+        (1, cfg.latent_channels, cfg.latent_size, cfg.latent_size),
+        jnp.float32)
+    ctx = jax.ShapeDtypeStruct((1, cfg.text_len, cfg.text_width), jnp.float32)
+    jax.eval_shape(lambda p, x, c: diffusion.apply_unet(
+        p["unet"], cfg, x, jnp.zeros((1,), jnp.int32), c), params, lat, ctx)
+    assert len(calls) == diffusion.flash_sites(cfg) == 3
+
+
+def test_diffusion_flash_attention_scope():
+    """The flash kernel's ops at a kernel-path shape (a narrow spatial
+    transformer over a 64x64 map) carry ``self_attn/flash_attention`` in
+    their op names, so the trace reader puts their time under
+    ``self_attn``."""
+    import re
+
+    from repro.models import diffusion
+    side = int(diffusion.FLASH_MIN_TOKENS ** 0.5)
+    p = jax.eval_shape(lambda k: diffusion.init_xattn(k, 16, 8, 2),
+                       jax.random.PRNGKey(0))
+    x = jax.ShapeDtypeStruct((1, 16, side, side), jnp.float32)
+    ctx = jax.ShapeDtypeStruct((1, 4, 8), jnp.float32)
+    hlo = jax.jit(lambda p, x, c: diffusion.apply_xattn(p, x, c, 2)).lower(
+        p, x, ctx).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', hlo))
+    kernel = [n for n in names if "/flash_attention/" in n]
+    assert kernel
+    assert all("/self_attn/flash_attention/" in n for n in kernel), kernel

@@ -110,7 +110,9 @@ def _engine_spans(trace_dir):
 
 def test_process_group_spans(dmodel, tmp_path):
     """A traced group shows the engine's host stages in order, each pack
-    with its request; a compile span appears on a cache miss only."""
+    with its request; a compile span appears on a cache miss only.  The
+    group and compile spans carry how many self-attention layers take the
+    flash kernel (none at this size)."""
     cfg, params = dmodel
     cost = CostParams(r_cloud=10.0, n_total=cfg.n_total_iterations,
                       n_step=cfg.split_stride, t_lim=5.0, k_decode=1.0)
@@ -125,7 +127,8 @@ def test_process_group_spans(dmodel, tmp_path):
     groups = [s for s in spans if s[2] == "repro.engine.process_group"]
     assert len(groups) == 2
     for (g0, g1, _, args), compiles in zip(groups, (1, 0)):
-        assert args == {"n_cloud": 2, "batch": 2, "request_ids": "r0;r1"}
+        assert args == {"n_cloud": 2, "batch": 2, "flash_sites": 0,
+                        "request_ids": "r0;r1"}
         inner = [s for s in spans if g0 <= s[0] and s[1] <= g1
                  and s[2] != "repro.engine.process_group"]
         assert [s[2].rsplit(".", 1)[1] for s in inner] == (
@@ -135,7 +138,7 @@ def test_process_group_spans(dmodel, tmp_path):
             {"request_id": "r0"}, {"request_id": "r1"}]
         for s in inner:
             if s[2].endswith(".compile"):
-                assert s[3] == {"n_cloud": 2, "batch": 2}
+                assert s[3] == {"n_cloud": 2, "batch": 2, "flash_sites": 0}
     assert engine.stats["cache_misses"] == 1
     assert engine.stats["cache_hits"] == 1
 
