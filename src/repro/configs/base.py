@@ -80,6 +80,7 @@ class ModelConfig:
     activation: str = "swiglu"      # swiglu | gelu | relu2
     qkv_bias: bool = False
     norm: str = "rmsnorm"           # rmsnorm | layernorm
+    norm_eps: float = 1e-6          # epsilon of every norm in the model
     rope_theta: float = 10000.0
     tie_embeddings: bool = False
 

@@ -219,17 +219,23 @@ def _qkv(p, h, cfg, positions, ctx=None):
 
 def apply_attn_block_seq(p, x, cfg, ctx, *, positions, causal=True,
                          enc_out=None, return_kv=False):
-    """Full-sequence attention block.  Returns (x, aux, kv | None)."""
-    h = apply_norm(p["norm1"], x)
-    q, k, v = _qkv(p, h, cfg, positions, ctx)
-    window = cfg.window if cfg.attention_kind == "swa" else 0
-    # positions here are always arange(S): use the flash (custom-vjp) path
-    o = attn_lib.self_attention(q, k, v, causal=causal, window=window)
-    o = _attn_sharded(o, ctx, "out")
-    x = x + jnp.einsum("bshe,hed->bsd", o, p["wo"])
-    x = _hidden_replicated(x, ctx)
+    """Full-sequence attention block.  Returns (x, aux, kv | None).
+
+    Self-attention (norm, q/k/v, RoPE, attention, output projection,
+    residual) carries the named scope ``self_attn`` and the MLP (norm,
+    MLP or MoE, residual) ``mlp``, by which a device trace puts the
+    block's time down to its parts."""
+    with jax.named_scope("self_attn"):
+        h = apply_norm(p["norm1"], x, cfg.norm_eps)
+        q, k, v = _qkv(p, h, cfg, positions, ctx)
+        window = cfg.window if cfg.attention_kind == "swa" else 0
+        # positions here are always arange(S): use the flash (custom-vjp) path
+        o = attn_lib.self_attention(q, k, v, causal=causal, window=window)
+        o = _attn_sharded(o, ctx, "out")
+        x = x + jnp.einsum("bshe,hed->bsd", o, p["wo"])
+        x = _hidden_replicated(x, ctx)
     if "xwq" in p and enc_out is not None:
-        hx = apply_norm(p["xnorm"], x)
+        hx = apply_norm(p["xnorm"], x, cfg.norm_eps)
         xq = jnp.einsum("bsd,dhe->bshe", hx, p["xwq"])
         xk = jnp.einsum("bsd,dhe->bshe", enc_out, p["xwk"])
         xv = jnp.einsum("bsd,dhe->bshe", enc_out, p["xwv"])
@@ -242,13 +248,14 @@ def apply_attn_block_seq(p, x, cfg, ctx, *, positions, causal=True,
         xo = _attn_sharded(xo, ctx, "out")
         x = x + jnp.einsum("bshe,hed->bsd", xo, p["xwo"])
         x = _hidden_replicated(x, ctx)
-    h2 = apply_norm(p["norm2"], x)
-    aux = None
-    if "moe" in p:
-        y, aux = moe_lib.apply_moe(p["moe"], h2, cfg, ctx)
-    else:
-        y = apply_mlp(p["mlp"], h2, cfg)
-    x = x + y
+    with jax.named_scope("mlp"):
+        h2 = apply_norm(p["norm2"], x, cfg.norm_eps)
+        aux = None
+        if "moe" in p:
+            y, aux = moe_lib.apply_moe(p["moe"], h2, cfg, ctx)
+        else:
+            y = apply_mlp(p["mlp"], h2, cfg)
+        x = x + y
     kv = {"k": k, "v": v} if return_kv else None
     return x, aux, kv
 
@@ -263,15 +270,15 @@ def apply_block_seq(kind, p, x, cfg, ctx, *, positions, state=None,
             return_kv=return_cache)
         return x, aux, kv
     if kind == "rec":
-        h = apply_norm(p["norm1"], x)
+        h = apply_norm(p["norm1"], x, cfg.norm_eps)
         y, new_state = rglru_lib.apply_rglru_block(
             p["rglru"], h, cfg, state=state, kernel_fn=kernels.get("rglru"))
         x = x + y
-        h2 = apply_norm(p["norm2"], x)
+        h2 = apply_norm(p["norm2"], x, cfg.norm_eps)
         x = x + apply_mlp(p["mlp"], h2, cfg)
         return x, None, (new_state if return_cache else None)
     if kind == "ssd":
-        h = apply_norm(p["norm1"], x)
+        h = apply_norm(p["norm1"], x, cfg.norm_eps)
         y, new_state = ssd_lib.apply_ssd_block(
             p["ssd"], h, cfg, state=state, kernel_fn=kernels.get("ssd"))
         x = x + y
@@ -366,7 +373,7 @@ def encode(params, frames, cfg, ctx):
 
     body_r = jax.checkpoint(body, policy=jax.checkpoint_policies.nothing_saveable)
     (x,), _ = jax.lax.scan(body_r, (frames,), enc["blocks"])
-    return apply_norm(enc["final_norm"], x)
+    return apply_norm(enc["final_norm"], x, cfg.norm_eps)
 
 
 def forward_hidden(params, batch, cfg, ctx: ShardCtx = LOCAL_CTX, *,
@@ -385,7 +392,7 @@ def forward_hidden(params, batch, cfg, ctx: ShardCtx = LOCAL_CTX, *,
     x, aux, caches = _scan_groups(
         params, x, cfg, ctx, positions=positions, enc_out=enc_out,
         return_cache=return_cache, remat=remat, kernels=kernels)
-    x = apply_norm(params["final_norm"], x)
+    x = apply_norm(params["final_norm"], x, cfg.norm_eps)
     if return_cache and enc_out is not None:
         caches["enc_out"] = enc_out
     return x, aux, caches
@@ -486,7 +493,7 @@ def init_decode_cache(cfg, batch: int, max_len: int):
 
 def _decode_attn(p, x, cfg, cache, position, enc_kv=None):
     """One-token attention block.  x (B,1,d)."""
-    h = apply_norm(p["norm1"], x)
+    h = apply_norm(p["norm1"], x, cfg.norm_eps)
     pos1 = position[None] if position.ndim == 0 else position
     q, k, v = _qkv(p, h, cfg, pos1)
     swa = cfg.attention_kind == "swa" and cfg.window
@@ -508,13 +515,13 @@ def _decode_attn(p, x, cfg, cache, position, enc_kv=None):
             kv_valid=kv_val[None])
     x = x + jnp.einsum("bshe,hed->bsd", o, p["wo"])
     if "xwq" in p and enc_kv is not None:
-        hx = apply_norm(p["xnorm"], x)
+        hx = apply_norm(p["xnorm"], x, cfg.norm_eps)
         xq = jnp.einsum("bsd,dhe->bshe", hx, p["xwq"])
         xo = attn_lib.attention_einsum(
             xq, enc_kv["k"], enc_kv["v"], q_positions=pos1,
             kv_positions=jnp.arange(enc_kv["k"].shape[1]), causal=False)
         x = x + jnp.einsum("bshe,hed->bsd", xo, p["xwo"])
-    h2 = apply_norm(p["norm2"], x)
+    h2 = apply_norm(p["norm2"], x, cfg.norm_eps)
     if "moe" in p:
         y, _ = moe_lib.apply_moe(p["moe"], h2, cfg, LOCAL_CTX)
     else:
@@ -526,13 +533,13 @@ def _decode_block(kind, p, x, cfg, cache, position, enc_kv=None):
     if kind == "attn":
         return _decode_attn(p, x, cfg, cache, position, enc_kv)
     if kind == "rec":
-        h = apply_norm(p["norm1"], x)
+        h = apply_norm(p["norm1"], x, cfg.norm_eps)
         y, new_state = rglru_lib.apply_rglru_block(p["rglru"], h, cfg, state=cache)
         x = x + y
-        h2 = apply_norm(p["norm2"], x)
+        h2 = apply_norm(p["norm2"], x, cfg.norm_eps)
         return x + apply_mlp(p["mlp"], h2, cfg), new_state
     if kind == "ssd":
-        h = apply_norm(p["norm1"], x)
+        h = apply_norm(p["norm1"], x, cfg.norm_eps)
         y, new_state = ssd_lib.apply_ssd_block(p["ssd"], h, cfg, state=cache)
         return x + y, new_state
     raise ValueError(kind)
@@ -593,7 +600,7 @@ def decode_step(params, token, cache, position, cfg,
         x, c = _decode_block(kind, params["tail"][f"t{i}"], x, cfg,
                              cache["tail"][f"t{i}"], position, tenc)
         new_tail[f"t{i}"] = c
-    x = apply_norm(params["final_norm"], x)
+    x = apply_norm(params["final_norm"], x, cfg.norm_eps)
     logits = unembed(params, x, cfg)
     new_cache = {"groups": new_groups, "tail": new_tail}
     if enc_stack is not None:

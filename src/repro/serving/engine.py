@@ -16,9 +16,11 @@ bytes shipped, which the benchmarks aggregate.
 
 ``DiffusionSplitEngine.process_group`` marks its host stages with
 profiler spans (``repro.engine.*``: process_group, encode_prompt,
-compile, denoise, pull, pack), and every jitted program here has a
-stable name (``jit_encode_prompt``, ``jit_denoise_range``,
-``jit_device_finish``, ``jit_cloud_layers``, ``jit_device_layers``).
+compile, denoise, pull, pack), ``LayerSplitEngine.process`` its own
+(process_layers, compile, cloud_layers, pull, pack), and every jitted
+program here has a stable name (``jit_encode_prompt``,
+``jit_denoise_range``, ``jit_device_finish``, ``jit_cloud_layers``,
+``jit_device_layers``).
 A span costs about a microsecond while no profiler runs.
 """
 from __future__ import annotations
@@ -288,7 +290,16 @@ class DiffusionDeviceSim:
 # Layer-granularity split for LM architectures
 # ==========================================================================
 class LayerSplitEngine:
-    """Cloud side of a layer split: embed + groups [0, g), ship hidden."""
+    """Cloud side of a layer split: embed + groups [0, g), ship hidden.
+
+    ``process`` marks its host stages with profiler spans:
+    ``repro.engine.process_layers`` around the call and, on an
+    executable-cache miss, ``repro.engine.compile`` (both with
+    ``stop_group``, ``batch`` and ``tokens``, the prompt length per
+    row); ``repro.engine.cloud_layers``, the compiled call through
+    ``block_until_ready``; ``repro.engine.pull``, the copy of the fp16
+    hidden states to the host; ``repro.engine.pack``, their byte count
+    and transfer time."""
 
     def __init__(self, params, cfg, link: LinkProfile = WAN_LINK):
         self.params = params
@@ -312,28 +323,40 @@ class LayerSplitEngine:
         def cloud_layers(params, batch):
             x = tr.embed_inputs(params, batch, cfg)
             positions = jnp.arange(x.shape[1])
+            # round to the fp16 payload here: the host then copies it as
+            # it ships and converts nothing
             return tr.run_layer_range(
                 params, x, cfg, LOCAL_CTX, start_group=0,
-                stop_group=stop_group, positions=positions)
-        t0 = time.perf_counter()
-        compiled = jax.jit(cloud_layers).lower(self.params, batch).compile()
-        self.stats["compile_seconds"] += time.perf_counter() - t0
+                stop_group=stop_group, positions=positions).astype(jnp.float16)
+        B, S = batch["tokens"].shape
+        with TraceAnnotation("repro.engine.compile", stop_group=stop_group,
+                             batch=B, tokens=S):
+            t0 = time.perf_counter()
+            compiled = jax.jit(cloud_layers).lower(self.params,
+                                                   batch).compile()
+            self.stats["compile_seconds"] += time.perf_counter() - t0
         self._exec_cache[key] = compiled
         self.stats["executables"] = len(self._exec_cache)
         return compiled
 
     def process(self, batch: Dict[str, np.ndarray], stop_group: int):
-        batch = {k: jnp.asarray(v) for k, v in batch.items()}
-        run = self._run_fn(stop_group, batch)
-        t0 = time.perf_counter()
-        hidden = run(self.params, batch)
-        hidden.block_until_ready()
-        self.stats["gpu_seconds"] += time.perf_counter() - t0
-        payload = np.asarray(hidden, np.float32).astype(np.float16)
-        self.stats["bytes_shipped"] += payload.nbytes
-        self.stats["requests"] += batch["tokens"].shape[0]
-        t_net = transmission_time(payload.nbytes, self.link)
-        return payload, t_net
+        B, S = batch["tokens"].shape
+        with TraceAnnotation("repro.engine.process_layers",
+                             stop_group=stop_group, batch=B, tokens=S):
+            batch = {k: jnp.asarray(v) for k, v in batch.items()}
+            run = self._run_fn(stop_group, batch)
+            with TraceAnnotation("repro.engine.cloud_layers"):
+                t0 = time.perf_counter()
+                hidden = run(self.params, batch)
+                hidden.block_until_ready()
+                self.stats["gpu_seconds"] += time.perf_counter() - t0
+            with TraceAnnotation("repro.engine.pull"):
+                payload = np.asarray(hidden)
+            with TraceAnnotation("repro.engine.pack"):
+                self.stats["bytes_shipped"] += payload.nbytes
+                t_net = transmission_time(payload.nbytes, self.link)
+            self.stats["requests"] += B
+            return payload, t_net
 
 
 class LayerSplitDevice:
@@ -359,7 +382,7 @@ class LayerSplitDevice:
                 x = tr.run_layer_range(
                     params, hidden, cfg, LOCAL_CTX, start_group=start_group,
                     stop_group=cfg.num_groups(), positions=positions)
-                x = tr.apply_norm(params["final_norm"], x)
+                x = tr.apply_norm(params["final_norm"], x, cfg.norm_eps)
                 return tr.unembed(params, x[:, -1:], cfg)
             t0 = time.perf_counter()
             run = jax.jit(device_layers).lower(self.params, hidden).compile()

@@ -235,3 +235,26 @@ def test_diffusion_flash_attention_scope():
     kernel = [n for n in names if "/flash_attention/" in n]
     assert kernel
     assert all("/self_attn/flash_attention/" in n for n in kernel), kernel
+
+
+def test_lm_named_scopes():
+    """The compiled cloud half of a layer split (``jit_cloud_layers``)
+    carries ``self_attn`` and ``mlp`` in its ops' ``op_name`` metadata,
+    the flash scan's ops under ``self_attn``: what the benchmark's trace
+    reader puts the LM layers' device time down to."""
+    import re
+
+    from repro.core.transport import LOCAL_LINK
+    from repro.serving.engine import LayerSplitEngine
+    cfg = reduced_config("h2o-danube-1.8b")
+    params = tr.init_params(cfg, jax.random.PRNGKey(0))
+    engine = LayerSplitEngine(params, cfg, link=LOCAL_LINK)
+    # 2048 tokens: prefill attention takes the chunked flash scan
+    engine.process({"tokens": np.ones((1, 2048), np.int32)}, 1)
+    (compiled,) = engine._exec_cache.values()
+    hlo = compiled.as_text()
+    assert hlo.split(",", 1)[0].split()[1] == "jit_cloud_layers"
+    names = set(re.findall(r'op_name="([^"]*)"', hlo))
+    assert any("/self_attn/flash_attention/" in n for n in names)
+    assert any("/self_attn/" in n and "dot_general" in n for n in names)
+    assert any("/mlp/" in n and "dot_general" in n for n in names)

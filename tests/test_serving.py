@@ -93,6 +93,30 @@ def test_layer_split_matches_full_forward():
         assert t_net > 0
 
 
+def test_layer_split_payload_is_fp16_of_hidden():
+    """The payload is the cloud layers' bf16 hidden states rounded once to
+    fp16, bit for bit what a host-side bf16 -> fp32 -> fp16 conversion
+    of the same states gives."""
+    from repro.models.moe import LOCAL_CTX
+    cfg = reduced_config("h2o-danube-1.8b")
+    params = tr.init_params(cfg, jax.random.PRNGKey(0))
+    toks = np.asarray(jax.random.randint(jax.random.PRNGKey(1), (2, 64), 0,
+                                         cfg.vocab_size))
+    payload, _ = LayerSplitEngine(params, cfg, link=LOCAL_LINK).process(
+        {"tokens": toks}, 1)
+
+    @jax.jit
+    def hidden(params, toks):
+        x = tr.embed_inputs(params, {"tokens": toks}, cfg)
+        return tr.run_layer_range(params, x, cfg, LOCAL_CTX, start_group=0,
+                                  stop_group=1,
+                                  positions=jnp.arange(x.shape[1]))
+    want = np.asarray(hidden(params, jnp.asarray(toks)), np.float32)
+    assert payload.dtype == np.float16
+    np.testing.assert_array_equal(payload.view(np.uint16),
+                                  want.astype(np.float16).view(np.uint16))
+
+
 def _engine_spans(trace_dir):
     """The ``repro.engine.*`` host events of the one profile under
     ``trace_dir``: (start ns, end ns, name, {arg: value}), in order."""
@@ -139,6 +163,33 @@ def test_process_group_spans(dmodel, tmp_path):
         for s in inner:
             if s[2].endswith(".compile"):
                 assert s[3] == {"n_cloud": 2, "batch": 2, "flash_sites": 0}
+    assert engine.stats["cache_misses"] == 1
+    assert engine.stats["cache_hits"] == 1
+
+
+def test_layer_split_spans(tmp_path):
+    """A traced ``LayerSplitEngine.process`` shows its host stages once
+    each per call, in order, the outer one and the compile (on a cache
+    miss only) with the split, batch and prompt length."""
+    cfg = reduced_config("h2o-danube-1.8b")
+    params = tr.init_params(cfg, jax.random.PRNGKey(0))
+    engine = LayerSplitEngine(params, cfg, link=LOCAL_LINK)
+    toks = np.ones((2, 16), np.int32)
+    with jax.profiler.trace(str(tmp_path)):
+        engine.process({"tokens": toks}, 1)          # compiles
+        engine.process({"tokens": toks}, 1)          # cached
+    spans = _engine_spans(tmp_path)
+    calls = [s for s in spans if s[2] == "repro.engine.process_layers"]
+    assert len(calls) == 2
+    args = {"stop_group": 1, "batch": 2, "tokens": 16}
+    for (c0, c1, _, got), compiles in zip(calls, (1, 0)):
+        assert got == args
+        inner = [s for s in spans if c0 <= s[0] and s[1] <= c1
+                 and s[2] != "repro.engine.process_layers"]
+        assert [s[2].rsplit(".", 1)[1] for s in inner] == (
+            ["compile"] * compiles + ["cloud_layers", "pull", "pack"])
+        for s in inner:
+            assert s[3] == (args if s[2].endswith(".compile") else {})
     assert engine.stats["cache_misses"] == 1
     assert engine.stats["cache_hits"] == 1
 
