@@ -7,8 +7,12 @@ Online-softmax attention with explicit VMEM tiling:
   scratch   acc (bq, d) f32, m (bq, 128) f32, l (bq, 128) f32 — persist
             across the kv grid dimension (canonical TPU flash pattern).
 
-Causal and sliding-window masks are applied per tile; tiles entirely
-outside the mask are skipped with ``pl.when`` (no MXU work issued).
+Causal and sliding-window masks are applied per tile.  With either set
+the kv grid axis spans only the widest band of kv blocks that one query
+block can see (``_band``): step ``ki`` of query block ``qi`` visits kv
+block ``lo(qi) + ki``, clamped to ``hi(qi)``.  A step past the band keeps
+the resident k/v blocks (same block index, so no copy) and does no MXU
+work (``pl.when``).  Without either the grid covers every kv block.
 GQA is handled in the k/v index_map (kv_head = q_head // group), so no
 materialized head repetition.
 
@@ -47,9 +51,21 @@ def _widen(col, n: int):
     return col[:, :1]
 
 
+def _band(qi, *, bq: int, bk: int, n_kv: int, causal: bool, window: int):
+    """First and last kv block that query block ``qi`` sees: none left of
+    its first query's window, none above its last query's diagonal.
+    Takes a Python int (the grid's size) or a traced grid index."""
+    static = isinstance(qi, int)
+    maximum, minimum = (max, min) if static else (jnp.maximum, jnp.minimum)
+    q_start = qi * bq
+    lo = maximum(q_start - window + 1, 0) // bk if window else 0
+    hi = minimum((q_start + bq - 1) // bk, n_kv - 1) if causal else n_kv - 1
+    return lo, hi
+
+
 def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
-            bq: int, bk: int, n_kv: int, causal: bool, window: int,
-            kv_len: int, scale: float, group: int):
+            bq: int, bk: int, n_kv: int, n_steps: int, causal: bool,
+            window: int, kv_len: int, scale: float, group: int):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
 
@@ -61,14 +77,16 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
     # tile bounds in token coordinates
     q_start = qi * bq
-    k_start = ki * bk
-    # causal: skip tiles fully above the diagonal; window: skip tiles fully
-    # left of every query's window.
-    run = jnp.bool_(True)
-    if causal:
-        run = jnp.logical_and(run, k_start <= q_start + bq - 1)
-    if window:
-        run = jnp.logical_and(run, k_start + bk - 1 > q_start - window)
+    if causal or window:
+        # the band holds every tile that meets the mask; steps past it
+        # (query blocks whose band is narrower than the grid) compute nothing
+        lo, hi = _band(qi, bq=bq, bk=bk, n_kv=n_kv, causal=causal,
+                       window=window)
+        k_start = (lo + ki) * bk
+        run = lo + ki <= hi
+    else:
+        k_start = ki * bk
+        run = jnp.bool_(True)
 
     @pl.when(run)
     def _compute():
@@ -97,7 +115,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
                             preferred_element_type=jnp.float32))
         m_ref[...] = m_new
 
-    @pl.when(ki == n_kv - 1)
+    @pl.when(ki == n_steps - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0] = (acc_ref[...] / _widen(l, acc_ref.shape[1])
@@ -124,16 +142,27 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     n_q, n_kv = Sq // bq, Skv // bk
     kv_len = Skv if kv_len is None else kv_len
 
-    kernel = functools.partial(
-        _kernel, bq=bq, bk=bk, n_kv=n_kv, causal=causal, window=window,
-        kv_len=kv_len, scale=scale, group=group_total)
+    if causal or window:
+        band = functools.partial(_band, bq=bq, bk=bk, n_kv=n_kv,
+                                 causal=causal, window=window)
+        n_steps = max(hi - lo + 1 for lo, hi in map(band, range(n_q)))
 
-    def kv_index(bh, qi, ki):
-        return (bh // group_total, ki, 0)
+        def kv_index(bh, qi, ki):
+            lo, hi = band(qi)
+            return (bh // group_total, jnp.minimum(lo + ki, hi), 0)
+    else:
+        n_steps = n_kv
+
+        def kv_index(bh, qi, ki):
+            return (bh // group_total, ki, 0)
+
+    kernel = functools.partial(
+        _kernel, bq=bq, bk=bk, n_kv=n_kv, n_steps=n_steps, causal=causal,
+        window=window, kv_len=kv_len, scale=scale, group=group_total)
 
     return pl.pallas_call(
         kernel,
-        grid=(BHq, n_q, n_kv),
+        grid=(BHq, n_q, n_steps),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
             pl.BlockSpec((1, bk, d), kv_index),

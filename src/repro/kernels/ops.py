@@ -134,5 +134,6 @@ int8_dequantize = _q8.int8_dequantize
 
 
 def kernel_registry():
-    """kernel_fn overrides for models.transformer (TPU path)."""
-    return {"rglru": rglru_scan, "ssd": ssd_scan}
+    """kernel_fn overrides for models.transformer (TPU path, forward only:
+    none of these kernels has a backward)."""
+    return {"rglru": rglru_scan, "ssd": ssd_scan, "attn": flash_attention}

@@ -245,12 +245,38 @@ def _flash_bwd_impl(causal, window, chunk_size, res, dout):
 flash_self_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
+#: Self-attention over at least this many tokens takes the flash path.
+FLASH_MIN_LEN = 2048
+#: The (q, kv) block pair of the Pallas flash kernel on the LM's
+#: forward-only path: the fastest of those measured on a v5e at
+#: h2o-danube-1.8b's shapes (GQA 32/8, head_dim 80, 8192 tokens, batch 1
+#: and 2).
+FLASH_BLOCKS = (512, 512)
+
+
+def takes_flash(seq_len: int, chunk_size: int = 1024,
+                flash_min_len: int = FLASH_MIN_LEN) -> bool:
+    """Whether ``self_attention`` over ``seq_len`` tokens takes the flash
+    path (the kernel hook or the scan) rather than the einsum."""
+    return (seq_len >= flash_min_len
+            and seq_len % min(chunk_size, seq_len) == 0)
+
+
 def self_attention(q, k, v, *, causal=True, window=0, chunk_size=1024,
-                   flash_min_len: int = 2048):
-    """Training/prefill self-attention dispatch: flash (custom-vjp,
-    memory-efficient backward) for long sequences, einsum for short."""
+                   flash_min_len: int = FLASH_MIN_LEN, kernel=None):
+    """Training/prefill self-attention dispatch: flash for long sequences,
+    einsum for short.
+
+    The flash path is ``kernel`` where a forward-only caller passes one
+    (``kernels.ops.flash_attention`` on the TPU: scores stay in VMEM and
+    tiles outside the causal window are skipped), else the jnp scan with
+    its custom VJP (memory-efficient backward), which training needs."""
     S = q.shape[1]
-    if S >= flash_min_len and S % min(chunk_size, S) == 0:
+    if takes_flash(S, chunk_size, flash_min_len):
+        if kernel is not None:
+            with jax.named_scope("flash_attention"):
+                return kernel(q, k, v, causal=causal, window=window,
+                              bq=FLASH_BLOCKS[0], bk=FLASH_BLOCKS[1])
         return flash_self_attention(q, k, v, causal, window, chunk_size)
     pos = jnp.arange(S)
     return attention_einsum(q, k, v, q_positions=pos, kv_positions=pos,

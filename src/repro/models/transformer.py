@@ -218,19 +218,21 @@ def _qkv(p, h, cfg, positions, ctx=None):
 
 
 def apply_attn_block_seq(p, x, cfg, ctx, *, positions, causal=True,
-                         enc_out=None, return_kv=False):
+                         enc_out=None, return_kv=False, attn_kernel=None):
     """Full-sequence attention block.  Returns (x, aux, kv | None).
 
     Self-attention (norm, q/k/v, RoPE, attention, output projection,
     residual) carries the named scope ``self_attn`` and the MLP (norm,
     MLP or MoE, residual) ``mlp``, by which a device trace puts the
-    block's time down to its parts."""
+    block's time down to its parts.  ``attn_kernel`` is the flash
+    kernel hook of ``attention.self_attention`` (forward only)."""
     with jax.named_scope("self_attn"):
         h = apply_norm(p["norm1"], x, cfg.norm_eps)
         q, k, v = _qkv(p, h, cfg, positions, ctx)
         window = cfg.window if cfg.attention_kind == "swa" else 0
-        # positions here are always arange(S): use the flash (custom-vjp) path
-        o = attn_lib.self_attention(q, k, v, causal=causal, window=window)
+        # positions here are always arange(S): the flash path applies
+        o = attn_lib.self_attention(q, k, v, causal=causal, window=window,
+                                    kernel=attn_kernel)
         o = _attn_sharded(o, ctx, "out")
         x = x + jnp.einsum("bshe,hed->bsd", o, p["wo"])
         x = _hidden_replicated(x, ctx)
@@ -267,7 +269,7 @@ def apply_block_seq(kind, p, x, cfg, ctx, *, positions, state=None,
     if kind == "attn":
         x, aux, kv = apply_attn_block_seq(
             p, x, cfg, ctx, positions=positions, enc_out=enc_out,
-            return_kv=return_cache)
+            return_kv=return_cache, attn_kernel=kernels.get("attn"))
         return x, aux, kv
     if kind == "rec":
         h = apply_norm(p["norm1"], x, cfg.norm_eps)
@@ -678,3 +680,17 @@ def run_layer_range(params, x, cfg, ctx, *, start_group: int, stop_group: int,
                 kind, params["tail"][f"t{i}"], x, cfg, ctx,
                 positions=positions, enc_out=enc_out, kernels=kernels)
     return x
+
+
+def flash_sites(cfg, seq_len: int, *, start_group: int = 0,
+                stop_group: int) -> int:
+    """How many self-attention layers of ``run_layer_range`` over groups
+    [start_group, stop_group) at ``seq_len`` tokens take the flash path
+    (the kernel where the caller passes ``kernels["attn"]``), from the
+    configuration and the length."""
+    if not attn_lib.takes_flash(seq_len):
+        return 0
+    kinds = cfg.block_pattern * (stop_group - start_group)
+    if stop_group == cfg.num_groups():
+        kinds += cfg.tail_pattern()
+    return kinds.count("attn")

@@ -45,6 +45,7 @@ from repro.core.transport import (
     transmission_time,
     unpack_boundary,
 )
+from repro.kernels import ops
 from repro.models import diffusion as dif
 from repro.models import transformer as tr
 from repro.models.moe import LOCAL_CTX
@@ -68,7 +69,6 @@ def pallas_rowwise_int8(x: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
     tests/test_kernels.py).  This is the ``rowwise`` hook
     ``pack_boundary_wire`` accepts, so engine payloads are quantized by
     the accelerator kernel rather than numpy."""
-    from repro.kernels import ops
     q, s = ops.int8_quantize(jnp.asarray(x, jnp.float32))
     return np.asarray(q), np.asarray(s)
 
@@ -295,11 +295,16 @@ class LayerSplitEngine:
     ``process`` marks its host stages with profiler spans:
     ``repro.engine.process_layers`` around the call and, on an
     executable-cache miss, ``repro.engine.compile`` (both with
-    ``stop_group``, ``batch`` and ``tokens``, the prompt length per
-    row); ``repro.engine.cloud_layers``, the compiled call through
-    ``block_until_ready``; ``repro.engine.pull``, the copy of the fp16
-    hidden states to the host; ``repro.engine.pack``, their byte count
-    and transfer time."""
+    ``stop_group``, ``batch``, ``tokens``, the prompt length per row,
+    and ``flash_sites``, the self-attention layers that run through the
+    flash kernel); ``repro.engine.cloud_layers``, the compiled call
+    through ``block_until_ready``; ``repro.engine.pull``, the copy of the
+    fp16 hidden states to the host; ``repro.engine.pack``, their byte
+    count and transfer time.
+
+    The cloud half runs forward only, so on the TPU it takes the Pallas
+    kernels of ``kernels.ops.kernel_registry``; elsewhere they would run
+    interpreted, which is for validation only."""
 
     def __init__(self, params, cfg, link: LinkProfile = WAN_LINK):
         self.params = params
@@ -309,6 +314,13 @@ class LayerSplitEngine:
         # carries the batch signature alongside the split point
         self._exec_cache: Dict[Tuple[int, Any], Any] = {}
         self.stats = _new_stats()
+        self.kernels = ops.kernel_registry() if ops.on_tpu() else None
+
+    def _span_args(self, stop_group: int, B: int, S: int):
+        sites = (tr.flash_sites(self.cfg, S, stop_group=stop_group)
+                 if self.kernels else 0)
+        return dict(stop_group=stop_group, batch=B, tokens=S,
+                    flash_sites=sites)
 
     def _run_fn(self, stop_group: int, batch):
         key = (stop_group, tuple(sorted(
@@ -327,10 +339,11 @@ class LayerSplitEngine:
             # it ships and converts nothing
             return tr.run_layer_range(
                 params, x, cfg, LOCAL_CTX, start_group=0,
-                stop_group=stop_group, positions=positions).astype(jnp.float16)
+                stop_group=stop_group, positions=positions,
+                kernels=self.kernels).astype(jnp.float16)
         B, S = batch["tokens"].shape
-        with TraceAnnotation("repro.engine.compile", stop_group=stop_group,
-                             batch=B, tokens=S):
+        with TraceAnnotation("repro.engine.compile",
+                             **self._span_args(stop_group, B, S)):
             t0 = time.perf_counter()
             compiled = jax.jit(cloud_layers).lower(self.params,
                                                    batch).compile()
@@ -342,7 +355,7 @@ class LayerSplitEngine:
     def process(self, batch: Dict[str, np.ndarray], stop_group: int):
         B, S = batch["tokens"].shape
         with TraceAnnotation("repro.engine.process_layers",
-                             stop_group=stop_group, batch=B, tokens=S):
+                             **self._span_args(stop_group, B, S)):
             batch = {k: jnp.asarray(v) for k, v in batch.items()}
             run = self._run_fn(stop_group, batch)
             with TraceAnnotation("repro.engine.cloud_layers"):

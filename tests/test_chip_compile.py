@@ -1,5 +1,6 @@
-"""Compile the served path's units at stable-diffusion-v1's published
-widths for one TPU v5e chip that is described, not attached.
+"""Compile the served path's units at stable-diffusion-v1's and
+h2o-danube-1.8b's published widths for one TPU v5e chip that is
+described, not attached.
 
 Nothing runs: these compiles catch what the chip's compiler refuses
 (tiling, VMEM, memory) at no chip time.  This is the only test file that
@@ -14,8 +15,10 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import h2o_danube_1_8b
 from repro.configs.stable_diffusion_v1 import CONFIG
 from repro.kernels import ops
+from repro.models import attention
 from repro.models import diffusion as dif
 
 LEVEL0 = CONFIG.unet_base                       # channels at the 64x64 level
@@ -112,3 +115,20 @@ def test_level0_resblock_compiles(one_chip):
     t_emb = jax.ShapeDtypeStruct((1, T_DIM), jnp.float32, sharding=one_chip)
     compiled = _compile(dif.apply_resblock, p, x, t_emb)
     assert compiled.memory_analysis().output_size_in_bytes >= 4 * x.size
+
+
+def test_danube_prefill_attention_compiles(one_chip, chip_kernels):
+    """The LM's forward-only self-attention at h2o-danube-1.8b's shapes
+    (8192 tokens, batch 2, GQA 32/8, head_dim 80, causal window 4096)
+    takes the flash kernel with its band of kv blocks."""
+    c = h2o_danube_1_8b.CONFIG
+    q = jax.ShapeDtypeStruct((2, 8192, c.num_heads, c.resolved_head_dim()),
+                             jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((2, 8192, c.num_kv_heads,
+                               c.resolved_head_dim()), jnp.bfloat16,
+                              sharding=one_chip)
+    compiled = _compile(
+        lambda q, k, v: attention.self_attention(
+            q, k, v, causal=True, window=c.window,
+            kernel=ops.kernel_registry()["attn"]), q, kv, kv)
+    assert "tpu_custom_call" in compiled.as_text()

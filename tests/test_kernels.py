@@ -10,8 +10,21 @@ from repro.kernels import ops, ref
 rng = np.random.default_rng(42)
 
 
-def _n(*shape, dtype=np.float32):
-    return jnp.asarray(rng.normal(size=shape), dtype)
+def _n(*shape, dtype=np.float32, gen=rng):
+    return jnp.asarray(gen.normal(size=shape), dtype)
+
+
+#: Cases with whole kv tiles outside the kernel's band at both ends.  They
+#: draw from a generator of their own, so the module's generator gives the
+#: tests after ``test_flash_attention`` the inputs it gave them before.
+BAND_CASES = [
+    # h2o-danube-1.8b's head ratio and head_dim: 4 of 8 kv blocks per
+    # query block
+    (1, 1024, 1024, 8, 2, 80, True, 384),
+    (1, 512, 512, 4, 2, 64, True, 0),       # causal only: steps past the diagonal
+    (1, 512, 512, 2, 1, 64, False, 200),    # window only: no diagonal
+]
+band_rng = np.random.default_rng(16)
 
 
 @pytest.mark.parametrize("B,Sq,Skv,Hq,Hkv,D,causal,win", [
@@ -21,12 +34,14 @@ def _n(*shape, dtype=np.float32):
     (1, 128, 128, 2, 2, 128, False, 0),     # non-causal (cross-attn)
     (1, 512, 512, 3, 3, 64, True, 128),     # odd heads
     (1, 256, 256, 2, 2, 40, False, 0),      # diffusion self-attn, padded d
-])
+] + BAND_CASES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_attention(B, Sq, Skv, Hq, Hkv, D, causal, win, dtype):
-    q = _n(B, Sq, Hq, D, dtype=dtype)
-    k = _n(B, Skv, Hkv, D, dtype=dtype)
-    v = _n(B, Skv, Hkv, D, dtype=dtype)
+    band = (B, Sq, Skv, Hq, Hkv, D, causal, win) in BAND_CASES
+    gen = band_rng if band else rng
+    q = _n(B, Sq, Hq, D, dtype=dtype, gen=gen)
+    k = _n(B, Skv, Hkv, D, dtype=dtype, gen=gen)
+    v = _n(B, Skv, Hkv, D, dtype=dtype, gen=gen)
     o = ops.flash_attention(q, k, v, causal=causal, window=win, bq=128,
                             bk=128)
     qf = q.transpose(0, 2, 1, 3).reshape(B * Hq, Sq, D)
@@ -118,6 +133,36 @@ def test_flash_custom_vjp_grads():
     # one-shot softmax, so the two sums differ by O(|sum| * eps * sqrt(N))
     # ~ 1e-4 — a relative comparison is the meaningful one here.
     assert abs(float(r - f)) < 1e-6 * max(1.0, abs(float(r)))
+    for a, b in zip(gr, gf):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+
+
+def test_self_attention_without_kernel_keeps_scan_vjp():
+    """With no kernel hook (what training passes, the registry being for
+    forward-only callers) the flash-length dispatch differentiates
+    through the scan's custom VJP and matches the einsum's gradients."""
+    B, S, Hq, Hkv, D = 1, 256, 4, 2, 64
+    from repro.models import attention as at
+    gen = np.random.default_rng(17)
+    q = _n(B, S, Hq, D, gen=gen)
+    k, v = _n(B, S, Hkv, D, gen=gen), _n(B, S, Hkv, D, gen=gen)
+    pos = jnp.arange(S)
+
+    def ref_loss(q, k, v):
+        o = at.attention_einsum(q, k, v, q_positions=pos, kv_positions=pos,
+                                causal=True, window=96)
+        return jnp.sum(jnp.tanh(o))
+
+    def dispatch_loss(q, k, v):
+        return jnp.sum(jnp.tanh(at.self_attention(
+            q, k, v, causal=True, window=96, chunk_size=64,
+            flash_min_len=S, kernel=None)))
+
+    assert "custom_vjp_call" in str(jax.make_jaxpr(dispatch_loss)(q, k, v))
+    r, gr = jax.value_and_grad(ref_loss, argnums=(0, 1, 2))(q, k, v)
+    f, gf = jax.value_and_grad(dispatch_loss, argnums=(0, 1, 2))(q, k, v)
+    # a sum of 65536 fp32 values in two orders: eps * sqrt(N) = 3e-5
+    np.testing.assert_allclose(float(f), float(r), rtol=3e-5)
     for a, b in zip(gr, gf):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
 

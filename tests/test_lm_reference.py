@@ -96,6 +96,65 @@ def test_flash_path_matches_reference():
     assert rel(logits, want_logits) < TOL
 
 
+def test_flash_kernel_path_matches_reference():
+    """At 2048 tokens ``run_layer_range`` given the kernel hook (the Pallas
+    flash kernel, interpreted here) and without it (the scan) both match
+    the reference, on a window that leaves whole kv blocks of the kernel
+    outside every query block's band."""
+    from repro.kernels import ops
+    from repro.models.moe import LOCAL_CTX
+    cfg = small_cfg(num_layers=2, window=700)
+    params = tr.init_params(cfg, jax.random.PRNGKey(6))
+    toks = np.asarray(jax.random.randint(jax.random.PRNGKey(7), (1, 2048), 1,
+                                         cfg.vocab_size))
+
+    def hidden(kernels):
+        @jax.jit
+        def run(params, toks):
+            x = tr.embed_inputs(params, {"tokens": toks}, cfg)
+            return tr.run_layer_range(
+                params, x, cfg, LOCAL_CTX, start_group=0, stop_group=2,
+                positions=jnp.arange(x.shape[1]), kernels=kernels)
+        return np.asarray(run(params, jnp.asarray(toks)), np.float32)
+
+    calls = []
+
+    def counted(q, k, v, **kw):
+        calls.append(kw)
+        return ops.flash_attention(q, k, v, **kw)
+
+    want, _ = ref.forward(params, cfg, toks, 2)
+    kernel = hidden({"attn": counted})
+    assert calls and all(kw["causal"] and kw["window"] == 700
+                         for kw in calls)
+    scan = hidden(None)
+    assert rel(kernel, want) < TOL
+    assert rel(scan, want) < TOL
+
+
+def test_flash_sites():
+    """``flash_sites`` counts the self-attention layers of a layer range
+    that take the flash path: every attention layer of the range at 2048
+    tokens or more, none below."""
+    c = h2o_danube_1_8b.CONFIG
+    assert tr.flash_sites(c, 8192, stop_group=18) == 18
+    assert tr.flash_sites(c, 2048, start_group=18, stop_group=24) == 6
+    assert tr.flash_sites(c, 2047, stop_group=18) == 0
+    assert tr.flash_sites(c, 1024, stop_group=24) == 0
+    # recurrent layers do not count; the tail's attention-free remainder
+    # neither (38 layers of (rec, rec, attn): 12 groups and a tail of 2)
+    g = reduced_config("recurrentgemma-9b")
+    g = dataclasses.replace(g, num_layers=38)
+    assert tr.flash_sites(g, 4096, stop_group=g.num_groups()) == 12
+    # the engine states them only where it passes the kernel
+    engine = LayerSplitEngine(None, c, link=LOCAL_LINK)
+    assert engine._span_args(18, 2, 8192)["flash_sites"] == (
+        18 if engine.kernels else 0)
+    engine.kernels = {"attn": None}
+    assert engine._span_args(18, 2, 8192) == {
+        "stop_group": 18, "batch": 2, "tokens": 8192, "flash_sites": 18}
+
+
 @pytest.mark.parametrize("eps", [1e-5, 1e-6])
 def test_norm_eps_reaches_every_norm(monkeypatch, eps):
     """Every norm of the prefill, decode and split paths is given the

@@ -170,7 +170,8 @@ def test_process_group_spans(dmodel, tmp_path):
 def test_layer_split_spans(tmp_path):
     """A traced ``LayerSplitEngine.process`` shows its host stages once
     each per call, in order, the outer one and the compile (on a cache
-    miss only) with the split, batch and prompt length."""
+    miss only) with the split, batch, prompt length and the layers that
+    take the flash kernel (none off the TPU, nor at this length)."""
     cfg = reduced_config("h2o-danube-1.8b")
     params = tr.init_params(cfg, jax.random.PRNGKey(0))
     engine = LayerSplitEngine(params, cfg, link=LOCAL_LINK)
@@ -181,7 +182,7 @@ def test_layer_split_spans(tmp_path):
     spans = _engine_spans(tmp_path)
     calls = [s for s in spans if s[2] == "repro.engine.process_layers"]
     assert len(calls) == 2
-    args = {"stop_group": 1, "batch": 2, "tokens": 16}
+    args = {"stop_group": 1, "batch": 2, "tokens": 16, "flash_sites": 0}
     for (c0, c1, _, got), compiles in zip(calls, (1, 0)):
         assert got == args
         inner = [s for s in spans if c0 <= s[0] and s[1] <= c1
