@@ -9,9 +9,9 @@ reduced stable-diffusion config and reconciles:
   * modeled vs MEASURED executable count and cache hit rate (the §4.3
     quantization claim: a whole fleet's dispatch stream compiles at
     most ``n_total/n_step + 1`` programs),
-  * modeled vs measured per-group GPU-seconds — a single calibration
-    ratio (CPU engine vs the modeled A100-class rate) plus per-group
-    relative deviation with a tolerance report; compile time is
+  * modeled GPU-seconds vs measured device seconds per group — a single
+    calibration ratio (CPU engine vs the modeled A100-class rate) plus
+    per-group relative deviation with a tolerance report; compile time is
     accounted separately (``stats["compile_seconds"]``, the PR-6
     engine bugfix) so the comparison is steady-state execution,
   * modeled vs measured boundary payload bytes (wire-format overhead
@@ -158,7 +158,7 @@ def main():
               f"bound {r['executable_bound']}), "
               f"hit_rate {r['measured_hit_rate']:.3f} "
               f"(modeled {r['modeled_hit_rate']:.3f}), "
-              f"gpu_s {r['gpu_seconds']:.2f} "
+              f"device_s {r['device_seconds']:.2f} "
               f"(+{r['compile_seconds']:.2f}s compile), "
               f"max_rel_dev {r['max_rel_dev']:.3f} "
               f"(tol {r['tolerance']}), "

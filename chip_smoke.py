@@ -89,23 +89,25 @@ def finite(x):
 
 
 def run_group(engine, members, n_cloud, label):
-    c0, e0 = engine.stats["compile_seconds"], engine.stats["gpu_seconds"]
+    c0 = engine.stats["compile_seconds"]
+    e0 = engine.stats["device_seconds"]
     results = engine.process_group(members, n_cloud, seed=SEED)
     print(f"group {label}: n_cloud={n_cloud} batch={len(members)} "
           f"wire={engine.wire}: compile "
           f"{engine.stats['compile_seconds'] - c0:.3f} s, execute "
-          f"{engine.stats['gpu_seconds'] - e0:.6f} s, payload "
+          f"{engine.stats['device_seconds'] - e0:.6f} s, payload "
           f"{len(results[0].payload)} B/request")
     return results
 
 
 def complete(device, result, label):
-    c0, e0 = device.stats["compile_seconds"], device.stats["gpu_seconds"]
+    c0 = device.stats["compile_seconds"]
+    e0 = device.stats["device_seconds"]
     img = device.complete(result)
     print(f"device {label}: [{result.n_cloud}, "
           f"{CONFIG.n_total_iterations}) + VAE: compile "
           f"{device.stats['compile_seconds'] - c0:.3f} s, execute "
-          f"{device.stats['gpu_seconds'] - e0:.6f} s, image "
+          f"{device.stats['device_seconds'] - e0:.6f} s, image "
           f"{tuple(img.shape)}")
     return img
 
@@ -190,7 +192,7 @@ def main():
     print(f"int8 vs fp32 wire: max |image diff| = "
           f"{float(jnp.max(jnp.abs(img_int8 - img_split))):.6g}")
 
-    denoise_hlo = fp32._exec_cache[(MID_SPLIT, len(mid))].as_text()
+    denoise_hlo = fp32.cache.programs[(MID_SPLIT, len(mid))].as_text()
     check(("tpu_custom_call" in denoise_hlo) == (dif.flash_sites(cfg) > 0),
           f"compiled denoise program holds the flash kernel "
           f"({dif.flash_sites(cfg)} sites per UNet run)")

@@ -1,8 +1,8 @@
 """Split serving across BOTH granularities + §7 refinements.
 
-1. Iteration split (diffusion) with paper-mode vs int8-quantized transport
-   and a lossy (UDP-style) channel — the paper's graceful-degradation
-   claim, measured as image correlation.
+1. Iteration split (diffusion) with the paper encoding vs the row-wise
+   int8 wire and a lossy (UDP-style) channel — the paper's
+   graceful-degradation claim, measured as image correlation.
 2. Layer split (qwen2-class LM): cloud runs pattern groups [0, g), ships
    the fp16 hidden boundary, device finishes; verifies the logits match
    the monolithic forward at every split point.
@@ -55,16 +55,16 @@ def diffusion_demo(smoke: bool = False):
 
     print("== diffusion iteration split ==")
     base_img = None
-    modes = ("paper",) if smoke else ("paper", "int8")
-    for mode in modes:
+    wires = (None,) if smoke else (None, "int8")
+    for wire in wires:
         eng = DiffusionSplitEngine(params, cfg, cost, link=LOCAL_LINK,
-                                   transfer_mode=mode)
+                                   wire=wire)
         res = eng.process_group([req], n, seed=0)[0]
         img = np.asarray(device.complete(res))
         if base_img is None:
             base_img = img
         corr = np.corrcoef(img.ravel(), base_img.ravel())[0, 1]
-        print(f"  mode={mode:6s} payload={len(res.payload):7d}B "
+        print(f"  wire={wire or 'paper':6s} payload={len(res.payload):7d}B "
               f"corr_vs_paper={corr:.4f}")
     if smoke:
         return
@@ -105,7 +105,7 @@ def layer_split_demo(smoke: bool = False):
 if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
-                    help="reduced CI run: one transfer mode, fewer splits")
+                    help="reduced CI run: one wire format, fewer splits")
     args = ap.parse_args()
     diffusion_demo(smoke=args.smoke)
     layer_split_demo(smoke=args.smoke)

@@ -81,52 +81,21 @@ def deserialize(data: bytes) -> Dict[str, np.ndarray]:
 
 
 # --------------------------------------------------------------------------
-# Quantized transfer (paper §7, implemented)
+# The boundary payload: the paper's encoding, and the decoder of every format
 # --------------------------------------------------------------------------
-def quantize_fp16(x: np.ndarray) -> np.ndarray:
-    return x.astype(np.float16)
-
-
-def quantize_int8(x: np.ndarray) -> Tuple[np.ndarray, float, float]:
-    """Affine int8 quantization.  Returns (q, scale, zero_point)."""
-    lo, hi = float(x.min()), float(x.max())
-    scale = max((hi - lo) / 255.0, 1e-12)
-    zp = lo
-    q = np.clip(np.round((x - zp) / scale), 0, 255).astype(np.uint8)
-    return q, scale, zp
-
-
-def dequantize_int8(q: np.ndarray, scale: float, zp: float) -> np.ndarray:
-    return q.astype(np.float32) * scale + zp
-
-
-def pack_boundary(latent: np.ndarray, context: Optional[np.ndarray], *,
-                  mode: str = "paper") -> bytes:
-    """Pack a diffusion split payload.
-
-    mode="paper": latent fp32 + context fp16 (paper Table 2 byte counts).
-    mode="int8":  both int8-quantized (§7 refinement; ~4x smaller).
-    """
-    tree: Dict[str, np.ndarray] = {}
-    if mode == "paper":
-        tree["latent"] = latent.astype(np.float32)
-        if context is not None:
-            tree["context"] = context.astype(np.float16)
-    elif mode == "int8":
-        q, s, z = quantize_int8(latent)
-        tree["latent"] = q
-        tree["latent_qparams"] = np.array([s, z], np.float32)
-        if context is not None:
-            qc, sc, zc = quantize_int8(context)
-            tree["context"] = qc
-            tree["context_qparams"] = np.array([sc, zc], np.float32)
-    else:
-        raise ValueError(mode)
+def pack_boundary(latent: np.ndarray,
+                  context: Optional[np.ndarray]) -> bytes:
+    """Pack a diffusion split payload in the paper's encoding: latent
+    fp32 + context fp16 (paper Table 2 byte counts).  Other encodings
+    ship through ``pack_boundary_wire``."""
+    tree: Dict[str, np.ndarray] = {"latent": latent.astype(np.float32)}
+    if context is not None:
+        tree["context"] = context.astype(np.float16)
     return serialize(tree)
 
 
 def unpack_boundary(data: bytes) -> Tuple[np.ndarray, Optional[np.ndarray]]:
-    """Decode any boundary payload (``pack_boundary`` modes and every
+    """Decode any boundary payload (``pack_boundary`` and every
     ``pack_boundary_wire`` format) back to fp32 latent + context."""
     tree = _decode_tree(deserialize(data))
     return tree["latent"].astype(np.float32), tree.get("context")
@@ -271,8 +240,7 @@ def _wire_tree(tree: Dict[str, np.ndarray], fmt: WireFormat,
     return out
 
 
-_WIRE_SUFFIXES = ("_rowscales", "_topk_vals", "_topk_idx", "_topk_shape",
-                  "_qparams")
+_WIRE_SUFFIXES = ("_rowscales", "_topk_vals", "_topk_idx", "_topk_shape")
 
 
 def _decode_tree(tree: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
@@ -294,9 +262,6 @@ def _decode_tree(tree: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
             deq = rowwise_dequantize_int8(x.reshape(rows, -1),
                                           tree[n + "_rowscales"])
             out[n] = deq.reshape(x.shape)
-        elif n + "_qparams" in tree:
-            s, z = tree[n + "_qparams"]
-            out[n] = dequantize_int8(x, float(s), float(z))
         else:
             out[n] = np.asarray(x, np.float32)
     return out

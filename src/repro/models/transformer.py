@@ -264,7 +264,9 @@ def apply_attn_block_seq(p, x, cfg, ctx, *, positions, causal=True,
 
 def apply_block_seq(kind, p, x, cfg, ctx, *, positions, state=None,
                     enc_out=None, return_cache=False, kernels=None):
-    """Returns (x, aux, cache_out).  cache_out pytree depends on kind."""
+    """Returns (x, aux, cache_out).  cache_out pytree depends on kind.
+    ``kernels`` (``ops.kernel_registry()``) is the hook of the forward-only
+    split path (``run_layer_range``): no Pallas kernel has a backward."""
     kernels = kernels or {}
     if kind == "attn":
         x, aux, kv = apply_attn_block_seq(
@@ -324,7 +326,7 @@ def unembed(params, h, cfg):
 # Full-sequence forward (train / prefill)
 # ==========================================================================
 def _scan_groups(params, x, cfg, ctx, *, positions, enc_out=None,
-                 return_cache=False, remat=True, kernels=None):
+                 return_cache=False, remat=True):
     """Run all pattern groups + tail.  Returns (x, aux_sum, caches)."""
     pattern = cfg.block_pattern
     n_aux = 2  # load_balance, router_z
@@ -335,7 +337,7 @@ def _scan_groups(params, x, cfg, ctx, *, positions, enc_out=None,
         for i, kind in enumerate(pattern):
             x, a, c = apply_block_seq(
                 kind, gp[f"b{i}"], x, cfg, ctx, positions=positions,
-                enc_out=enc_out, return_cache=return_cache, kernels=kernels)
+                enc_out=enc_out, return_cache=return_cache)
             if a is not None:
                 aux = aux + jnp.stack([a["load_balance"], a["router_z"]])
             if return_cache:
@@ -353,7 +355,7 @@ def _scan_groups(params, x, cfg, ctx, *, positions, enc_out=None,
     for i, kind in enumerate(cfg.tail_pattern()):
         x, a, c = apply_block_seq(
             kind, params["tail"][f"t{i}"], x, cfg, ctx, positions=positions,
-            enc_out=enc_out, return_cache=return_cache, kernels=kernels)
+            enc_out=enc_out, return_cache=return_cache)
         if a is not None:
             aux = aux + jnp.stack([a["load_balance"], a["router_z"]])
         if return_cache:
@@ -379,7 +381,7 @@ def encode(params, frames, cfg, ctx):
 
 
 def forward_hidden(params, batch, cfg, ctx: ShardCtx = LOCAL_CTX, *,
-                   return_cache=False, remat=True, kernels=None):
+                   return_cache=False, remat=True):
     """Embed + all blocks.  Returns (hidden (B,S,d), aux (2,), caches)."""
     enc_out = None
     if cfg.encoder_layers:
@@ -393,7 +395,7 @@ def forward_hidden(params, batch, cfg, ctx: ShardCtx = LOCAL_CTX, *,
     positions = jnp.arange(x.shape[1])
     x, aux, caches = _scan_groups(
         params, x, cfg, ctx, positions=positions, enc_out=enc_out,
-        return_cache=return_cache, remat=remat, kernels=kernels)
+        return_cache=return_cache, remat=remat)
     x = apply_norm(params["final_norm"], x, cfg.norm_eps)
     if return_cache and enc_out is not None:
         caches["enc_out"] = enc_out
@@ -444,13 +446,12 @@ def lm_loss(params, hidden, targets, mask, cfg, *, chunk: int = 512,
     return total / denom
 
 
-def train_forward(params, batch, cfg, ctx: ShardCtx = LOCAL_CTX, *,
-                  kernels=None):
+def train_forward(params, batch, cfg, ctx: ShardCtx = LOCAL_CTX):
     """batch: tokens (B,S), labels (B,S), mask (B,S) [+ frontend].
 
     Returns (loss, metrics dict).
     """
-    hidden, aux, _ = forward_hidden(params, batch, cfg, ctx, kernels=kernels)
+    hidden, aux, _ = forward_hidden(params, batch, cfg, ctx)
     loss = lm_loss(params, hidden, batch["labels"], batch["mask"], cfg)
     metrics = {"nll": loss}
     if cfg.moe is not None:
@@ -635,12 +636,11 @@ def pad_kv_caches(caches, pad_to: int):
     return {k: (fix(v) if k != "enc_kv" else v) for k, v in caches.items()}
 
 
-def prefill(params, batch, cfg, ctx: ShardCtx = LOCAL_CTX, *, kernels=None,
+def prefill(params, batch, cfg, ctx: ShardCtx = LOCAL_CTX, *,
             pad_to: int = 0):
     """Full-sequence prefill.  Returns (last-token logits, decode cache)."""
     hidden, _, caches = forward_hidden(
-        params, batch, cfg, ctx, return_cache=True, remat=False,
-        kernels=kernels)
+        params, batch, cfg, ctx, return_cache=True, remat=False)
     logits = unembed(params, hidden[:, -1:], cfg)
     if cfg.encoder_layers:
         caches["enc_kv"] = build_enc_kv(params, caches.pop("enc_out"), cfg)
@@ -658,6 +658,7 @@ def run_layer_range(params, x, cfg, ctx, *, start_group: int, stop_group: int,
 
     Static bounds => one compiled executable per split point; the scheduler's
     n_step quantization bounds how many of these exist (paper §4.3).
+    Forward only, so it alone passes on the ``kernels`` hook.
     """
     G = cfg.num_groups()
     assert 0 <= start_group <= stop_group <= G

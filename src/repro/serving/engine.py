@@ -11,8 +11,10 @@ in the zoo (the generalization of the paper's RegNet Table 1 splitting):
 cloud runs pattern groups [0, g), ships the hidden boundary, the device
 finishes [g, G) + the LM head.
 
-Both engines measure their own executable-cache size, GPU-seconds and
-bytes shipped, which the benchmarks aggregate.
+Each engine and device side holds one ``ProgramCache``: its compiled
+programs by key and its stats (executable count, cache hits and misses,
+compile and device seconds, bytes shipped), which the benchmarks
+aggregate.
 
 ``DiffusionSplitEngine.process_group`` marks its host stages with
 profiler spans (``repro.engine.*``: process_group, encode_prompt,
@@ -48,18 +50,62 @@ from repro.core.transport import (
 from repro.kernels import ops
 from repro.models import diffusion as dif
 from repro.models import transformer as tr
+from repro.models.common import pdtype
 from repro.models.moe import LOCAL_CTX
 
 
-#: Unified stats schema — BOTH engines (and the replay reconciler,
-#: serving.replay) report exactly these keys.  ``gpu_seconds`` is
-#: steady-state execution only; compilation is accounted separately in
-#: ``compile_seconds`` (an executable-cache miss warms the program via
-#: AOT lower+compile BEFORE the timed region, so a request's
-#: cloud_seconds never includes jit compile time).
-ENGINE_STATS_KEYS = ("gpu_seconds", "compile_seconds", "bytes_shipped",
+#: Unified stats schema — every engine and device side (and the replay
+#: reconciler, serving.replay) reports exactly these keys.
+#: ``device_seconds`` is steady-state execution only; compilation is
+#: accounted separately in ``compile_seconds`` (a cache miss compiles
+#: the program AOT BEFORE the timed region, so a request's cloud_seconds
+#: never includes compile time).
+ENGINE_STATS_KEYS = ("device_seconds", "compile_seconds", "bytes_shipped",
                      "requests", "executables", "cache_hits",
                      "cache_misses")
+
+
+class ProgramCache:
+    """Compiled programs by key, and the stats (``ENGINE_STATS_KEYS``)
+    of the engine that holds the cache.
+
+    A compiled program is shape-specialized, so each caller's key
+    carries what fixes its shapes.  Neither method takes a lock: the
+    engines' callers may compile different keys on several threads."""
+
+    def __init__(self):
+        self.programs: Dict[Any, Any] = {}
+        self.stats: Dict[str, Any] = {
+            k: 0.0 if k.endswith("_seconds") else 0 for k in ENGINE_STATS_KEYS}
+
+    def program(self, key, fn, *example_args, **span_args):
+        """The compiled ``fn`` for ``key``.  On a miss, lower and compile
+        it for ``example_args`` inside a ``repro.engine.compile`` span
+        carrying ``span_args``, charged to ``compile_seconds``.  ``fn``
+        goes to ``jax.jit`` as it is, so the program is named
+        ``jit_<fn.__name__>``."""
+        compiled = self.programs.get(key)
+        if compiled is not None:
+            self.stats["cache_hits"] += 1
+            return compiled
+        self.stats["cache_misses"] += 1
+        with TraceAnnotation("repro.engine.compile", **span_args):
+            t0 = time.perf_counter()
+            compiled = jax.jit(fn).lower(*example_args).compile()
+            self.stats["compile_seconds"] += time.perf_counter() - t0
+        self.programs[key] = compiled
+        self.stats["executables"] = len(self.programs)
+        return compiled
+
+    def run(self, program, *args):
+        """Call ``program`` through ``block_until_ready``, charge the
+        seconds to ``device_seconds`` and return ``(out, seconds)``."""
+        t0 = time.perf_counter()
+        out = program(*args)
+        out.block_until_ready()
+        seconds = time.perf_counter() - t0
+        self.stats["device_seconds"] += seconds
+        return out, seconds
 
 
 def pallas_rowwise_int8(x: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
@@ -76,12 +122,6 @@ def pallas_rowwise_int8(x: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
 #: the text encoder as one compiled program per (cfg, batch) rather than
 #: an eager dispatch per op
 _encode_prompt = jax.jit(dif.encode_prompt, static_argnums=1)
-
-
-def _new_stats() -> Dict[str, Any]:
-    return {"gpu_seconds": 0.0, "compile_seconds": 0.0,
-            "bytes_shipped": 0, "requests": 0, "executables": 0,
-            "cache_hits": 0, "cache_misses": 0}
 
 
 @dataclasses.dataclass
@@ -103,18 +143,17 @@ class SplitResult:
 
 class DiffusionSplitEngine:
     def __init__(self, params, cfg, cost: CostParams,
-                 link: LinkProfile = WAN_LINK, transfer_mode: str = "paper",
+                 link: LinkProfile = WAN_LINK,
                  planner: Optional[Planner] = None,
                  wire: Optional[str] = None):
         self.params = params
         self.cfg = cfg
         self.cost = cost
         self.link = link
-        self.transfer_mode = transfer_mode
-        #: wire-format name (core.transport.WIRE_FORMATS): when set it
-        #: overrides ``transfer_mode`` and payloads ship through
-        #: ``pack_boundary_wire`` with the Pallas int8 kernel as the
-        #: row-wise quantizer; None keeps the legacy pack_boundary modes
+        #: wire-format name (core.transport.WIRE_FORMATS): when set,
+        #: payloads ship through ``pack_boundary_wire`` with the Pallas
+        #: int8 kernel as the row-wise quantizer; None ships the paper's
+        #: encoding (``pack_boundary``: latent fp32 + context fp16)
         self.wire = wire
         # the shared decision-maker: assign() delegates here, so the
         # engine runs the exact per-request policy the simulators and
@@ -125,38 +164,12 @@ class DiffusionSplitEngine:
         # pre-planner solve bit-exactly for any c_batch
         self.planner = planner if planner is not None else Planner(
             cost, policy="variable", solve_c_batch=cost.c_batch)
-        self._exec_cache: Dict[Tuple[int, int], Any] = {}
-        self.stats = _new_stats()
+        #: one compiled denoise program per (n_cloud, batch)
+        self.cache = ProgramCache()
+        self.stats = self.cache.stats
         #: self-attention layers per UNet run that take the flash kernel,
         #: recorded on the process_group and compile spans
         self.flash_sites = dif.flash_sites(cfg)
-
-    # -- executable cache: one COMPILED program per (n_final, batch) -------
-    def _denoise_fn(self, n_cloud: int, batch: int, latent, ctx2):
-        """Return the compiled denoise executable for this key, warming
-        it (AOT lower+compile, charged to stats["compile_seconds"]) on a
-        miss — so process_group's timed region measures steady-state
-        execution only."""
-        key = (n_cloud, batch)
-        cached = self._exec_cache.get(key)
-        if cached is not None:
-            self.stats["cache_hits"] += 1
-            return cached
-        self.stats["cache_misses"] += 1
-        cfg = self.cfg
-
-        def denoise_range(params, latent, ctx2):
-            return dif.denoise_range(params, cfg, latent, ctx2, 0,
-                                     n_cloud)
-        with TraceAnnotation("repro.engine.compile", n_cloud=n_cloud,
-                             batch=batch, flash_sites=self.flash_sites):
-            t0 = time.perf_counter()
-            compiled = jax.jit(denoise_range).lower(
-                self.params, latent, ctx2).compile()
-            self.stats["compile_seconds"] += time.perf_counter() - t0
-        self._exec_cache[key] = compiled
-        self.stats["executables"] = len(self._exec_cache)
-        return compiled
 
     def assign(self, device: DeviceProfile) -> int:
         """Thin delegate into the unified planner: split solve + step
@@ -194,14 +207,17 @@ class DiffusionSplitEngine:
                     jax.random.PRNGKey(seed),
                     (B, cfg.latent_channels, cfg.latent_size,
                      cfg.latent_size))
-            gpu_s = 0.0
+            device_s = 0.0
             if n_cloud > 0:
-                run = self._denoise_fn(n_cloud, B, latent, ctx2)  # warm first
+                def denoise_range(params, latent, ctx2):
+                    return dif.denoise_range(params, cfg, latent, ctx2, 0,
+                                             n_cloud)
+                run = self.cache.program(
+                    (n_cloud, B), denoise_range, self.params, latent, ctx2,
+                    n_cloud=n_cloud, batch=B, flash_sites=self.flash_sites)
                 with TraceAnnotation("repro.engine.denoise"):
-                    t0 = time.perf_counter()
-                    latent = run(self.params, latent, ctx2)
-                    latent.block_until_ready()
-                    gpu_s = time.perf_counter() - t0
+                    latent, device_s = self.cache.run(run, self.params,
+                                                      latent, ctx2)
             results = []
             with TraceAnnotation("repro.engine.pull"):
                 lat_np = np.asarray(latent, np.float32)
@@ -216,15 +232,13 @@ class DiffusionSplitEngine:
                             lat_np[i], ctx_i, self.wire,
                             rowwise=pallas_rowwise_int8)
                     else:
-                        payload = pack_boundary(lat_np[i], ctx_i,
-                                                mode=self.transfer_mode)
+                        payload = pack_boundary(lat_np[i], ctx_i)
                     t_net = transmission_time(len(payload), self.link)
                 results.append(SplitResult(
                     request_id=r.request_id, n_cloud=n_cloud,
-                    payload=payload, cloud_seconds=gpu_s / B,
+                    payload=payload, cloud_seconds=device_s / B,
                     transfer_seconds=t_net))
                 self.stats["bytes_shipped"] += len(payload)
-            self.stats["gpu_seconds"] += gpu_s
             self.stats["requests"] += B
             return results
 
@@ -248,8 +262,9 @@ class DiffusionDeviceSim:
     def __init__(self, params, cfg):
         self.params = params
         self.cfg = cfg
-        self._finish_cache: Dict[Tuple[int, int], Any] = {}
-        self.stats = _new_stats()
+        #: one compiled finish-and-decode program per (n_cloud, batch)
+        self.cache = ProgramCache()
+        self.stats = self.cache.stats
 
     def complete(self, result: SplitResult):
         cfg = self.cfg
@@ -261,28 +276,16 @@ class DiffusionDeviceSim:
         else:
             ctx2 = jnp.zeros((2, latent.shape[0], cfg.text_len,
                               cfg.text_width), jnp.float32)
-        key = (n0, latent.shape[0])
-        run = self._finish_cache.get(key)
-        if run is None:
-            self.stats["cache_misses"] += 1
+        B = latent.shape[0]
 
-            def device_finish(params, latent, ctx2):
-                out = dif.denoise_range(params, cfg, latent, ctx2, n0,
-                                        cfg.n_total_iterations)
-                return dif.apply_vae_decoder(params["vae"], cfg, out)
-            t0 = time.perf_counter()
-            run = jax.jit(device_finish).lower(self.params, latent,
-                                               ctx2).compile()
-            self.stats["compile_seconds"] += time.perf_counter() - t0
-            self._finish_cache[key] = run
-            self.stats["executables"] = len(self._finish_cache)
-        else:
-            self.stats["cache_hits"] += 1
-        t0 = time.perf_counter()
-        out = run(self.params, latent, ctx2)
-        out.block_until_ready()
-        self.stats["gpu_seconds"] += time.perf_counter() - t0
-        self.stats["requests"] += latent.shape[0]
+        def device_finish(params, latent, ctx2):
+            out = dif.denoise_range(params, cfg, latent, ctx2, n0,
+                                    cfg.n_total_iterations)
+            return dif.apply_vae_decoder(params["vae"], cfg, out)
+        run = self.cache.program((n0, B), device_finish, self.params, latent,
+                                 ctx2, n_cloud=n0, batch=B)
+        out, _ = self.cache.run(run, self.params, latent, ctx2)
+        self.stats["requests"] += B
         return out
 
 
@@ -310,10 +313,9 @@ class LayerSplitEngine:
         self.params = params
         self.cfg = cfg
         self.link = link
-        # a compiled executable is shape-specialized, so the cache key
-        # carries the batch signature alongside the split point
-        self._exec_cache: Dict[Tuple[int, Any], Any] = {}
-        self.stats = _new_stats()
+        #: one compiled program per (stop_group, batch signature)
+        self.cache = ProgramCache()
+        self.stats = self.cache.stats
         self.kernels = ops.kernel_registry() if ops.on_tpu() else None
 
     def _span_args(self, stop_group: int, B: int, S: int):
@@ -322,47 +324,28 @@ class LayerSplitEngine:
         return dict(stop_group=stop_group, batch=B, tokens=S,
                     flash_sites=sites)
 
-    def _run_fn(self, stop_group: int, batch):
-        key = (stop_group, tuple(sorted(
-            (k, v.shape, str(v.dtype)) for k, v in batch.items())))
-        cached = self._exec_cache.get(key)
-        if cached is not None:
-            self.stats["cache_hits"] += 1
-            return cached
-        self.stats["cache_misses"] += 1
-        cfg = self.cfg
-
-        def cloud_layers(params, batch):
-            x = tr.embed_inputs(params, batch, cfg)
-            positions = jnp.arange(x.shape[1])
-            # round to the fp16 payload here: the host then copies it as
-            # it ships and converts nothing
-            return tr.run_layer_range(
-                params, x, cfg, LOCAL_CTX, start_group=0,
-                stop_group=stop_group, positions=positions,
-                kernels=self.kernels).astype(jnp.float16)
-        B, S = batch["tokens"].shape
-        with TraceAnnotation("repro.engine.compile",
-                             **self._span_args(stop_group, B, S)):
-            t0 = time.perf_counter()
-            compiled = jax.jit(cloud_layers).lower(self.params,
-                                                   batch).compile()
-            self.stats["compile_seconds"] += time.perf_counter() - t0
-        self._exec_cache[key] = compiled
-        self.stats["executables"] = len(self._exec_cache)
-        return compiled
-
     def process(self, batch: Dict[str, np.ndarray], stop_group: int):
+        cfg = self.cfg
         B, S = batch["tokens"].shape
-        with TraceAnnotation("repro.engine.process_layers",
-                             **self._span_args(stop_group, B, S)):
+        span_args = self._span_args(stop_group, B, S)
+        with TraceAnnotation("repro.engine.process_layers", **span_args):
             batch = {k: jnp.asarray(v) for k, v in batch.items()}
-            run = self._run_fn(stop_group, batch)
+
+            def cloud_layers(params, batch):
+                x = tr.embed_inputs(params, batch, cfg)
+                positions = jnp.arange(x.shape[1])
+                # round to the fp16 payload here: the host then copies it
+                # as it ships and converts nothing
+                return tr.run_layer_range(
+                    params, x, cfg, LOCAL_CTX, start_group=0,
+                    stop_group=stop_group, positions=positions,
+                    kernels=self.kernels).astype(jnp.float16)
+            key = (stop_group, tuple(sorted(
+                (k, v.shape, str(v.dtype)) for k, v in batch.items())))
+            run = self.cache.program(key, cloud_layers, self.params, batch,
+                                     **span_args)
             with TraceAnnotation("repro.engine.cloud_layers"):
-                t0 = time.perf_counter()
-                hidden = run(self.params, batch)
-                hidden.block_until_ready()
-                self.stats["gpu_seconds"] += time.perf_counter() - t0
+                hidden, _ = self.cache.run(run, self.params, batch)
             with TraceAnnotation("repro.engine.pull"):
                 payload = np.asarray(hidden)
             with TraceAnnotation("repro.engine.pack"):
@@ -378,35 +361,25 @@ class LayerSplitDevice:
     def __init__(self, params, cfg):
         self.params = params
         self.cfg = cfg
-        self._exec_cache: Dict[Tuple[int, Any], Any] = {}
-        self.stats = _new_stats()
+        #: one compiled program per (start_group, hidden shape)
+        self.cache = ProgramCache()
+        self.stats = self.cache.stats
 
     def complete(self, hidden_fp16: np.ndarray, start_group: int):
         cfg = self.cfg
-        from repro.models.common import pdtype
         hidden = jnp.asarray(hidden_fp16).astype(pdtype(cfg))
-        key = (start_group, hidden.shape)
-        run = self._exec_cache.get(key)
-        if run is None:
-            self.stats["cache_misses"] += 1
 
-            def device_layers(params, hidden):
-                positions = jnp.arange(hidden.shape[1])
-                x = tr.run_layer_range(
-                    params, hidden, cfg, LOCAL_CTX, start_group=start_group,
-                    stop_group=cfg.num_groups(), positions=positions)
-                x = tr.apply_norm(params["final_norm"], x, cfg.norm_eps)
-                return tr.unembed(params, x[:, -1:], cfg)
-            t0 = time.perf_counter()
-            run = jax.jit(device_layers).lower(self.params, hidden).compile()
-            self.stats["compile_seconds"] += time.perf_counter() - t0
-            self._exec_cache[key] = run
-            self.stats["executables"] = len(self._exec_cache)
-        else:
-            self.stats["cache_hits"] += 1
-        t0 = time.perf_counter()
-        out = run(self.params, hidden)
-        out.block_until_ready()
-        self.stats["gpu_seconds"] += time.perf_counter() - t0
-        self.stats["requests"] += hidden.shape[0]
+        def device_layers(params, hidden):
+            positions = jnp.arange(hidden.shape[1])
+            x = tr.run_layer_range(
+                params, hidden, cfg, LOCAL_CTX, start_group=start_group,
+                stop_group=cfg.num_groups(), positions=positions)
+            x = tr.apply_norm(params["final_norm"], x, cfg.norm_eps)
+            return tr.unembed(params, x[:, -1:], cfg)
+        B, S = hidden.shape[:2]
+        run = self.cache.program((start_group, hidden.shape), device_layers,
+                                 self.params, hidden, start_group=start_group,
+                                 batch=B, tokens=S)
+        out, _ = self.cache.run(run, self.params, hidden)
+        self.stats["requests"] += B
         return out

@@ -29,7 +29,7 @@ compiled programs.  This module is the bridge (ROADMAP item 1):
    through a real ``DiffusionSplitEngine`` executable cache on a small
    config (``configs/stable_diffusion_v1.reduced()``): each distinct
    ``(n_final, batch)`` group becomes a real ``process_group`` call, so
-   compile count, cache hit rate, per-group GPU-seconds and bytes
+   compile count, cache hit rate, per-group device seconds and bytes
    shipped are *measured*, not assumed.  ``reconcile`` then compares
    them against the simulator's modeled ``service`` seconds and payload
    bytes with a tolerance report (``benchmarks/engine_replay.py`` pins
@@ -363,7 +363,7 @@ class EngineReplayReport:
     modeled_hit_rate: float
     measured_hit_rate: float
     # accounting (engine.stats after the run)
-    gpu_seconds: float            # steady-state execution only
+    device_seconds: float         # steady-state execution only
     compile_seconds: float        # reported separately (the PR-6 bugfix)
     bytes_shipped: int
     requests: int
@@ -426,7 +426,7 @@ def replay_through_engine(trace: Trace, engine=None, eng_cfg=None,
                           wire: Optional[str] = None) -> EngineReplayReport:
     """Execute the trace's dispatch records through a real
     ``DiffusionSplitEngine`` executable cache and reconcile measured
-    compile/cache/GPU-seconds/bytes against the modeled numbers.
+    compile/cache/device-seconds/bytes against the modeled numbers.
 
     ``engine=None`` builds one on the reduced stable-diffusion config
     (CPU-sized); pass an engine to reuse compiled executables across
@@ -498,7 +498,7 @@ def replay_through_engine(trace: Trace, engine=None, eng_cfg=None,
         reqs = [Request(rid, DeviceProfile(rid, 1.0), toks, toks)
                 for rid in rec["members"]]
         results = engine.process_group(reqs, n_scaled, seed=seed)
-        measured_s = sum(r.cloud_seconds for r in results)   # = gpu_s
+        measured_s = sum(r.cloud_seconds for r in results)   # = device_s
         measured_bytes = len(results[0].payload)
         g = groups.get(key)
         if g is None:
@@ -541,7 +541,7 @@ def replay_through_engine(trace: Trace, engine=None, eng_cfg=None,
         modeled_hit_rate=modeled_hits / executed if executed else 0.0,
         measured_hit_rate=(meas_hits / (meas_hits + meas_misses)
                            if meas_hits + meas_misses else 0.0),
-        gpu_seconds=stats["gpu_seconds"],
+        device_seconds=stats["device_seconds"],
         compile_seconds=stats["compile_seconds"],
         bytes_shipped=stats["bytes_shipped"],
         requests=stats["requests"],

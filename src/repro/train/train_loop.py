@@ -13,7 +13,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -36,13 +36,12 @@ class TrainConfig:
 
 
 def make_train_step(model_cfg, train_cfg: TrainConfig,
-                    ctx: ShardCtx = LOCAL_CTX, kernels=None,
+                    ctx: ShardCtx = LOCAL_CTX,
                     donate: bool = True) -> Callable:
     opt_cfg = train_cfg.optimizer
 
     def loss_fn(params, batch):
-        return tr.train_forward(params, batch, model_cfg, ctx,
-                                kernels=kernels)
+        return tr.train_forward(params, batch, model_cfg, ctx)
 
     def step_fn(params, opt_state, batch):
         (loss, metrics), grads = jax.value_and_grad(
@@ -65,7 +64,6 @@ class TrainLoop:
     data_cfg: DataConfig
     train_cfg: TrainConfig
     ctx: ShardCtx = LOCAL_CTX
-    kernels: Optional[Dict] = None
 
     def init_or_resume(self, seed: int = 0):
         params = tr.init_params(self.model_cfg, jax.random.PRNGKey(seed))
@@ -91,8 +89,7 @@ class TrainLoop:
         Returns (params, opt_state, history list of metric dicts).
         """
         params, opt_state, start = self.init_or_resume(seed)
-        step_fn = make_train_step(self.model_cfg, self.train_cfg, self.ctx,
-                                  self.kernels)
+        step_fn = make_train_step(self.model_cfg, self.train_cfg, self.ctx)
         history = []
         t0 = time.perf_counter()
         for step in range(start, start + num_steps):

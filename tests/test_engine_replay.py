@@ -10,9 +10,10 @@ Covers the PR-6 acceptance criteria:
   * replay determinism: same trace + same seed -> identical counters;
   * engine replay: compile count == distinct scaled (n_final, batch)
     keys == the engine's own executable counter, under the §4.3 bound;
-  * the engine accounting bugfixes: compile time out of gpu_seconds,
-    cache hit/miss counters, PlanCache-backed assign(), and the
-    unified stats schema across both engines.
+  * the engine accounting bugfixes: compile time out of device_seconds,
+    cache hit/miss counters in every engine's ProgramCache,
+    PlanCache-backed assign(), and the unified stats schema across both
+    engines.
 
 Engine-executing tests use the reduced config on CPU and assert only
 deterministic counters — never wall-clock seconds (beyond sign).
@@ -26,15 +27,18 @@ import pytest
 
 import jax
 
-from repro.configs import stable_diffusion_v1
+from repro.configs import reduced_config, stable_diffusion_v1
 from repro.core.cost_model import CostParams
 from repro.core.planner import TRACE_FIELDS, PlanRequest, Planner
 from repro.core.telemetry import DeviceProfile
 from repro.core.transport import LOCAL_LINK
 from repro.models import diffusion
+from repro.models.transformer import init_params as lm_init
 from repro.serving.engine import (
     ENGINE_STATS_KEYS,
+    DiffusionDeviceSim,
     DiffusionSplitEngine,
+    LayerSplitDevice,
     LayerSplitEngine,
     Request,
 )
@@ -239,10 +243,10 @@ def test_replay_compile_count_is_distinct_scaled_keys(traced_small):
         // cfg.split_stride + 1
     assert report.executed == 8
     assert report.skipped == len(trace.dispatches()) - 8
-    # accounting: compile time exists, is NOT inside gpu_seconds, and
+    # accounting: compile time exists, is NOT inside device_seconds, and
     # both are positive; every request shipped real bytes
     assert report.compile_seconds > 0
-    assert report.gpu_seconds > 0
+    assert report.device_seconds > 0
     assert report.bytes_shipped > 0
     assert report.requests == sum(r["batch"]
                                   for r in trace.dispatches()[:8])
@@ -283,29 +287,71 @@ def _mk_requests(cfg, n, start=0):
                     toks, toks) for i in range(n)]
 
 
-def test_compile_time_split_from_gpu_seconds(dmodel):
-    """An executable-cache miss charges compile_seconds, NOT
-    gpu_seconds; a repeat group compiles nothing more."""
-    cfg, params = dmodel
+def _diffusion_engine(cfg, params):
     cost = CostParams(r_cloud=10.0, n_total=cfg.n_total_iterations,
                       n_step=cfg.split_stride, t_lim=5.0, k_decode=1.0)
-    eng = DiffusionSplitEngine(params, cfg, cost, link=LOCAL_LINK)
-    eng.process_group(_mk_requests(cfg, 1), n_cloud=4)
+    return DiffusionSplitEngine(params, cfg, cost, link=LOCAL_LINK)
+
+
+def _lm():
+    lcfg = reduced_config("qwen2-7b")
+    return lcfg, lm_init(lcfg, jax.random.PRNGKey(0))
+
+
+def _diffusion_cloud(cfg, params):
+    eng = _diffusion_engine(cfg, params)
+    return eng, lambda i: eng.process_group(_mk_requests(cfg, 1, start=i),
+                                            n_cloud=4)
+
+
+def _diffusion_device(cfg, params):
+    (res,) = _diffusion_engine(cfg, params).process_group(
+        _mk_requests(cfg, 1), n_cloud=4)
+    device = DiffusionDeviceSim(params, cfg)
+    return device, lambda i: device.complete(res)
+
+
+def _layer_cloud(cfg, params):
+    lcfg, lparams = _lm()
+    eng = LayerSplitEngine(lparams, lcfg, link=LOCAL_LINK)
+    batch = {"tokens": np.zeros((1, 8), np.int32)}
+    return eng, lambda i: eng.process(batch, stop_group=1)
+
+
+def _layer_device(cfg, params):
+    lcfg, lparams = _lm()
+    payload, _ = LayerSplitEngine(lparams, lcfg, link=LOCAL_LINK).process(
+        {"tokens": np.zeros((1, 8), np.int32)}, stop_group=1)
+    device = LayerSplitDevice(lparams, lcfg)
+    return device, lambda i: device.complete(payload, 1)
+
+
+@pytest.mark.parametrize("make", [_diffusion_cloud, _diffusion_device,
+                                  _layer_cloud, _layer_device],
+                         ids=["DiffusionSplitEngine", "DiffusionDeviceSim",
+                              "LayerSplitEngine", "LayerSplitDevice"])
+def test_compile_time_split_from_device_seconds(dmodel, make):
+    """Every engine's program cache: a miss charges compile_seconds, NOT
+    device_seconds; a repeat key is a hit and compiles nothing more."""
+    eng, call = make(*dmodel)
+    call(0)
     s = eng.stats
     assert s["executables"] == s["cache_misses"] == 1
     assert s["cache_hits"] == 0
     assert s["compile_seconds"] > 0
-    assert s["gpu_seconds"] > 0
+    assert s["device_seconds"] > 0
+    assert s["requests"] == 1
     compile_after_first = s["compile_seconds"]
     # warm path: same key -> a hit, no new compile time
-    eng.process_group(_mk_requests(cfg, 1, start=1), n_cloud=4)
+    call(1)
     assert s["cache_hits"] == 1
-    assert s["cache_misses"] == 1
+    assert s["cache_misses"] == s["executables"] == 1
     assert s["compile_seconds"] == compile_after_first
+    assert s["requests"] == 2
     # steady-state execution is far cheaper than compilation here; the
-    # old accounting (compile inside gpu_s) made request 1 look ~10x
-    # slower than request 2 — now both execution timings are same-scale
-    assert s["compile_seconds"] > s["gpu_seconds"]
+    # old accounting (compile inside the timed call) made request 1 look
+    # ~10x slower than request 2 — now both timings are same-scale
+    assert s["compile_seconds"] > s["device_seconds"]
 
 
 def test_engine_assign_uses_plan_cache(dmodel):
@@ -342,30 +388,11 @@ def test_engine_assign_uses_plan_cache(dmodel):
 def test_unified_stats_schema(dmodel):
     """Both engines (and both device sims) report the same stats keys —
     the replay reconciler reads either."""
-    from repro.configs import reduced_config
-    from repro.models.transformer import init_params as lm_init
-    from repro.serving.engine import (
-        DiffusionDeviceSim,
-        LayerSplitDevice,
-    )
     cfg, params = dmodel
-    cost = CostParams(r_cloud=10.0, n_total=cfg.n_total_iterations,
-                      n_step=cfg.split_stride, t_lim=5.0, k_decode=1.0)
-    d_eng = DiffusionSplitEngine(params, cfg, cost, link=LOCAL_LINK)
-    lcfg = reduced_config("qwen2-7b")
-    lparams = lm_init(lcfg, jax.random.PRNGKey(0))
+    d_eng = _diffusion_engine(cfg, params)
+    lcfg, lparams = _lm()
     l_eng = LayerSplitEngine(lparams, lcfg, link=LOCAL_LINK)
     assert set(d_eng.stats) == set(l_eng.stats) == set(ENGINE_STATS_KEYS)
     assert set(DiffusionDeviceSim(params, cfg).stats) \
         == set(LayerSplitDevice(lparams, lcfg).stats) \
         == set(ENGINE_STATS_KEYS)
-    # LayerSplitEngine now actually counts executables + split timings
-    batch = {"tokens": np.zeros((1, 8), np.int32)}
-    l_eng.process(batch, stop_group=1)
-    l_eng.process(batch, stop_group=1)
-    assert l_eng.stats["executables"] == 1
-    assert l_eng.stats["cache_misses"] == 1
-    assert l_eng.stats["cache_hits"] == 1
-    assert l_eng.stats["compile_seconds"] > 0
-    assert l_eng.stats["gpu_seconds"] > 0
-    assert l_eng.stats["requests"] == 2

@@ -251,7 +251,7 @@ def test_lm_named_scopes():
     engine = LayerSplitEngine(params, cfg, link=LOCAL_LINK)
     # 2048 tokens: prefill attention takes the chunked flash scan
     engine.process({"tokens": np.ones((1, 2048), np.int32)}, 1)
-    (compiled,) = engine._exec_cache.values()
+    (compiled,) = engine.cache.programs.values()
     hlo = compiled.as_text()
     assert hlo.split(",", 1)[0].split()[1] == "jit_cloud_layers"
     names = set(re.findall(r'op_name="([^"]*)"', hlo))

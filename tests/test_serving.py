@@ -217,8 +217,8 @@ def test_engine_programs_have_stable_names(dmodel):
 
     def names(cache):
         return {c.as_text().split(",", 1)[0].split()[1]
-                for c in cache.values()}
-    assert names(engine._exec_cache) == {"jit_denoise_range"}
-    assert names(device._finish_cache) == {"jit_device_finish"}
-    assert names(cloud._exec_cache) == {"jit_cloud_layers"}
-    assert names(phone._exec_cache) == {"jit_device_layers"}
+                for c in cache.programs.values()}
+    assert names(engine.cache) == {"jit_denoise_range"}
+    assert names(device.cache) == {"jit_device_finish"}
+    assert names(cloud.cache) == {"jit_cloud_layers"}
+    assert names(phone.cache) == {"jit_device_layers"}

@@ -86,18 +86,17 @@ def test_sla_relaxation_reduces_cloud_work():
 
 
 def test_quantized_transport_end_to_end():
-    """§7 refinement: int8 boundary transfer still reconstructs images
-    (graceful degradation) at ~4x less traffic."""
+    """§7 refinement: the row-wise int8 wire still reconstructs images
+    (graceful degradation) at under half the paper encoding's bytes."""
     cfg = stable_diffusion_v1.reduced()
     params = diffusion.init_params(cfg, jax.random.PRNGKey(0))
     cost = CostParams(r_cloud=40.0, n_total=cfg.n_total_iterations,
                       n_step=cfg.split_stride, t_lim=3.0, k_decode=1.0)
     toks = np.zeros((1, cfg.text_len), np.int32)
     req = Request("r", DeviceProfile("d", 2.0, rtt=0.05), toks, toks)
-    paper_e = DiffusionSplitEngine(params, cfg, cost, link=LOCAL_LINK,
-                                   transfer_mode="paper")
+    paper_e = DiffusionSplitEngine(params, cfg, cost, link=LOCAL_LINK)
     int8_e = DiffusionSplitEngine(params, cfg, cost, link=LOCAL_LINK,
-                                  transfer_mode="int8")
+                                  wire="int8")
     device = DiffusionDeviceSim(params, cfg)
     n = cfg.split_stride * 2
     r_paper = paper_e.process_group([req], n, seed=0)[0]
